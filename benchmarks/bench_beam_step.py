@@ -131,11 +131,9 @@ def _exchange_sweep(quick: bool) -> dict:
     return rows
 
 
-def _fused_step_timing() -> dict | None:
+def _fused_step_timing() -> dict:
     """compiled-vs-interpret wall clock of ONE fused beam step on the
-    pallas engine (None when pallas is unavailable)."""
-    if not distance_mod.pallas_available():
-        return None
+    pallas engine."""
     rng = np.random.default_rng(0)
     n, d, rows = 2048, 64, 256
     base = rng.standard_normal((n, d)).astype(np.float32)

@@ -85,7 +85,7 @@ def calibrate_backend(
     ids_big = rng.integers(0, n, big).astype(np.int64)
 
     eng = distance_mod.get_engine(name)
-    resolved = eng.name  # pallas may have degraded to batch
+    resolved = eng.name
     # warm up: registers the table and compiles/jits the kernel wrappers so
     # the timed calls see the steady-state dispatch cost, not compile time
     eng.estimate(qb, pq, ids_small)
@@ -156,16 +156,11 @@ def calibrate_backend(
 
 def run(quick: bool = True, backends: list[str] | None = None) -> dict:
     if backends is None:
-        backends = ["scalar", "batch"]
-        if distance_mod.pallas_available():
-            backends.append("pallas")
+        backends = ["scalar", "batch", "pallas"]
     n, big, reps = (4096, 1024, 3) if quick else (16384, 4096, 7)
 
     records = {}
     for name in backends:
-        # keyed by requested name; apply_calibration looks up the RESOLVED
-        # backend, so a pallas-degraded-to-batch run reads the "batch" row
-        # (each record also carries the resolved name it measured)
         records[name] = calibrate_backend(name, n=n, big=big, reps=reps)
 
     rows = [

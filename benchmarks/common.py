@@ -17,6 +17,7 @@ from repro.core import dataset as dataset_mod  # noqa: E402
 from repro.core import distance as distance_mod  # noqa: E402
 from repro.core import vamana  # noqa: E402
 from repro.core.quant import RabitQuantizer  # noqa: E402
+from repro.kernels import interpret_mode  # noqa: E402
 
 CACHE_DIR = os.path.join(os.path.dirname(__file__), ".cache")
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out")
@@ -29,8 +30,8 @@ def set_backend(name: str) -> None:
 
 
 def active_backend() -> str:
-    """The engine name systems will actually get — 'auto'/'default' resolved,
-    pallas-without-jax degradation applied — so results.json records reality."""
+    """The engine name systems will actually get ('default' resolved), so
+    results.json records reality."""
     return distance_mod.resolved_backend()
 
 
@@ -93,40 +94,14 @@ def scheduler_active() -> dict:
     return {"policy": scheduler, "sla_ms": sla_ms}
 
 
-def set_platform(platform: str = "cpu") -> None:
-    """Pin the JAX platform (and its XLA tuning flags) BEFORE any kernel
-    traces — only takes effect at the beginning of the program.  No-op when
-    jax is absent (the host backends need no platform pin)."""
-    try:
-        import jax
-    except ImportError:
-        return
-    jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        # https://jax.readthedocs.io/en/latest/gpu_performance_tips.html
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_gpu_triton_gemm_any=True"
-            + " --xla_gpu_enable_latency_hiding_scheduler=true"
-        ).strip()
-
-
-_PALLAS_MODE_CACHE: dict[str, bool] = {}
-
-
 def pallas_mode() -> bool | None:
-    """Whether the pallas backend would run the kernels in interpret mode
-    (True) or compiled (False); None when the active backend isn't pallas.
+    """Whether the pallas backend runs its kernels in interpret mode (True)
+    or compiled (False); None when the active backend isn't pallas.
     Recorded in results.json so runs on real accelerators are
-    distinguishable from CPU interpret-mode runs.  Cached: the probe builds
-    an engine, and the answer cannot change within a process."""
+    distinguishable from CPU interpret-mode runs."""
     if active_backend() != "pallas":
         return None
-    if "interpret" not in _PALLAS_MODE_CACHE:
-        _PALLAS_MODE_CACHE["interpret"] = bool(
-            distance_mod.get_engine("pallas").interpret
-        )
-    return _PALLAS_MODE_CACHE["interpret"]
+    return interpret_mode()
 
 
 def best_of(fn, reps: int = 5) -> float:

@@ -1,15 +1,13 @@
-"""Distributed vector search over a virtual device mesh (device plane).
+"""Distributed vector search over a device mesh (device plane).
 
-Shards a corpus over 8 virtual devices, runs the kernel-backed two-stage
+Shards a corpus over every device JAX has, runs the kernel-backed two-stage
 compressed scan per shard under shard_map, merges with a distributed top-k —
 the same program the 512-chip veloann dry-run cell lowers.
 
-  PYTHONPATH=src python examples/distributed_search.py
+  PYTHONPATH=src python examples/distributed_search.py        # the chips
+  XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python examples/distributed_search.py    # 4 CPU devices
 """
-
-import os
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import sys
 import dataclasses
@@ -28,8 +26,8 @@ from repro.velo.index import DeviceIndex, from_host
 
 
 def main():
-    n_shards = 8
-    ds = dataset.make_dataset(n=4096, d=64, n_queries=64, k=10, seed=3)
+    n_shards = jax.device_count()
+    ds = dataset.make_dataset(n=512 * n_shards, d=64, n_queries=64, k=10, seed=3)
     per = ds.n // n_shards
     qb = RabitQuantizer(64, seed=0).fit_encode(ds.base)
 

@@ -185,7 +185,7 @@ class SystemConfig:
     group_demote: bool = False    # clock demotes co-admitted groups together
     track_access: bool = False    # per-vertex/page counters (Fig. 4)
     seed: int = 0
-    distance_backend: str = "default"  # scalar | batch | pallas | auto | default
+    distance_backend: str = "default"  # scalar | batch | pallas | default
     fuse: bool | None = None      # cross-query fused dispatch (None -> process default)
     fuse_rows: int | None = None  # rendezvous flush row budget (None -> default)
     shared_rendezvous: bool | None = None  # one system-wide rendezvous buffer
@@ -400,8 +400,8 @@ def build_system(
         ),
     )
     cost = cost or CostModel()
-    # ONE engine per system (it also answers which backend actually resolved
-    # — pallas may degrade to batch — for the calibration lookup)
+    # ONE engine per system (its name resolves "default" for the
+    # calibration lookup)
     dist_engine = distance_mod.get_engine(
         config.distance_backend, resident=config.resident_plane
     )

@@ -9,14 +9,12 @@ distance evaluated by the search plane goes through one of these engines:
   * ``batch``  — vectorized NumPy over whole code matrices (the default).
     One BLAS/ufunc dispatch per frontier batch instead of per vertex.
   * ``pallas`` — the JAX/Pallas kernels (kernels/binary_ip, kernels/int4_dist)
-    in interpret mode on CPU, compiled on real accelerators.  Falls back to
-    ``batch`` automatically when JAX is not importable.
+    in interpret mode on CPU, compiled on the TPU.
 
 Selection:
 
-  get_engine("scalar" | "batch" | "pallas" | "auto" | "default" | None)
+  get_engine("scalar" | "batch" | "pallas" | "default" | None)
 
-``auto`` resolves to ``pallas`` when JAX is available, else ``batch``.
 ``default`` (and None) resolve to the process-wide default set with
 ``set_default_backend`` — the hook benchmarks/run.py's ``--backend`` flag
 threads through without touching every call site.
@@ -47,7 +45,6 @@ callers can report how much work the plane absorbed per batch.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import numpy as np
 
@@ -68,7 +65,7 @@ _DEFAULT_BACKEND = "batch"
 def set_default_backend(name: str) -> None:
     """Set the process-wide default backend (see ``get_engine``)."""
     global _DEFAULT_BACKEND
-    if name not in BACKENDS and name != "auto":
+    if name not in BACKENDS:
         raise ValueError(f"unknown distance backend {name!r}; expected {BACKENDS}")
     _DEFAULT_BACKEND = name
 
@@ -78,18 +75,9 @@ def default_backend() -> str:
 
 
 def resolved_backend(name: str | None = None) -> str:
-    """The engine name ``get_engine(name)`` would actually serve — resolves
-    ``default``/``auto`` and the pallas-without-jax degradation."""
+    """The engine name ``get_engine(name)`` would serve (resolves
+    ``default``)."""
     return get_engine(name).name
-
-
-def pallas_available() -> bool:
-    try:
-        import jax  # noqa: F401
-
-        return True
-    except Exception:  # pragma: no cover - exercised only without jax
-        return False
 
 
 @dataclasses.dataclass
@@ -959,20 +947,16 @@ class PallasEngine(BatchEngine):
     def __init__(self, interpret: bool | None = None, bucket: int = 64,
                  resident: bool = True):
         super().__init__(resident=resident)
-        import jax  # raises if jax missing
-        import jax.numpy as jnp  # noqa: F401
+        import jax.numpy as jnp
 
+        from repro.kernels import interpret_mode
         from repro.kernels.binary_ip import estimate_dist2 as _binary_est
         from repro.kernels.int4_dist import int4_dist2 as _int4_dist2
 
-        if interpret is None:
-            # interpret mode on CPU (Pallas has no CPU lowering), compiled
-            # kernels on real accelerators
-            interpret = jax.default_backend() == "cpu"
         self._jnp = jnp
         self._binary_est = _binary_est
         self._int4_dist2 = _int4_dist2
-        self.interpret = interpret
+        self.interpret = interpret_mode(interpret)
         self.bucket = bucket
 
     def _build_table(self, qb: QuantizedBase):
@@ -1261,22 +1245,12 @@ def get_engine(name: str | None = None, resident: bool = True) -> DistanceEngine
     path (per-call row uploads) — the parity/ablation baseline."""
     if name is None or name == "default":
         name = _DEFAULT_BACKEND
-    if name == "auto":
-        name = "pallas" if pallas_available() else "batch"
     if name == "scalar":
         return ScalarEngine(resident=resident)
     if name == "batch":
         return BatchEngine(resident=resident)
     if name == "pallas":
-        try:
-            return PallasEngine(resident=resident)
-        except ImportError as e:  # no jax: degrade, keep serving
-            warnings.warn(
-                f"pallas distance backend unavailable ({e}); using batch",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            return BatchEngine(resident=resident)
+        return PallasEngine(resident=resident)
     raise ValueError(f"unknown distance backend {name!r}; expected {BACKENDS}")
 
 

@@ -12,4 +12,20 @@ Kernels:
   flash_attention LM prefill attention (causal / sliding window / bidir, GQA)
   paged_attention LM decode through a record-level KV block table — the
                   paper's record_map indirection applied to the KV cache
+
+Every kernel entry point takes ``interpret: bool | None = None`` and resolves
+it through ``interpret_mode``: the Pallas interpreter runs only where JAX's
+default backend is the CPU, which has no Pallas TPU lowering.  An explicit
+value overrides (compile-for-TPU tests pass False; timing harnesses pass True
+to compare against the compiled kernel).
 """
+
+import jax
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Whether a Pallas call runs in interpret mode: ``interpret`` when
+    given, else True exactly when ``jax.default_backend() == "cpu"``."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)

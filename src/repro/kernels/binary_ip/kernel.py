@@ -2,14 +2,22 @@
 
 Hardware adaptation (DESIGN.md §2): on CPUs RaBitQ's level-1 distance is a
 popcount-Hamming loop; the TPU has no popcount but has a 128x128 systolic MXU.
-We therefore unpack the bit codes to {-1,+1} lanes *inside VMEM* and issue a
-dense GEMM — arithmetic intensity d/8 bytes -> 2d flops per code row makes
-this compute-bound on the MXU for d >= 128, which is exactly where we want
-the level-1 scan to sit.
+We therefore unpack the bit codes to {-1,+1} *inside VMEM* and issue dense
+GEMMs — d/8 bytes per code row feed 2d flops.
 
-Tiling: queries (BQ=128 rows) x codes (BN=256 rows) per grid cell; the full
-code row (d/8 bytes, d <= 2048) lives in VMEM: VMEM use per cell =
-BQ*d*4 + BN*d/8 + BQ*BN*4 ~= 1.4 MB at d=1024 — comfortably under 16 MB.
+Layout: bit j of code byte b is dimension 8b+j.  Unpacking that interleave
+inside the kernel would put an 8-wide minor axis on the 128-lane axis, which
+pads 16x in VMEM (the compiler refused d=960 at 18.7 MB of scoped VMEM).
+Instead the wrapper de-interleaves the QUERY once — plane j holds the query's
+columns j, 8+j, 16+j, ... as a (8, B, d/8) array — and the kernel sums eight
+bit-plane GEMMs of width d/8, each against ``(codes >> j) & 1`` mapped to ±1.
+No reshape of the code tile ever happens.
+
+Tiling: queries (BQ=128 rows) x codes (BN=256 rows) per grid cell; the whole
+d/8-byte code row and the 8 query planes live in VMEM.  The TPU v5e compiler
+reports a scoped VMEM allocation of 180 KB per cell at every d from 96 to 960
+(the lane-interleaved unpack it replaces needed 18.7 MB at d=960, over the
+16 MB limit).
 """
 
 from __future__ import annotations
@@ -20,36 +28,38 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 
 DEFAULT_BQ = 128
 DEFAULT_BN = 256
 
 
-def _binary_ip_kernel(q_ref, codes_ref, out_ref):
-    q = q_ref[...]                                 # (BQ, d) f32
+def _binary_ip_kernel(qp_ref, codes_ref, out_ref):
     c = codes_ref[...].astype(jnp.int32)           # (BN, d/8) u8 -> i32
-    shifts = jnp.arange(8, dtype=jnp.int32)
-    bits = (c[:, :, None] >> shifts[None, None, :]) & 1
-    signs = (2 * bits - 1).reshape(c.shape[0], -1).astype(jnp.float32)  # (BN, d)
-    out_ref[...] = jax.lax.dot_general(
-        q.astype(jnp.float32),
-        signs,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for j in range(8):
+        signs = (2 * ((c >> j) & 1) - 1).astype(jnp.float32)   # (BN, d/8)
+        acc += jax.lax.dot_general(
+            qp_ref[j].astype(jnp.float32),         # (BQ, d/8) plane j
+            signs,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bn", "interpret"))
 def binary_ip_pallas(
-    q: jnp.ndarray,        # (B, d) float
-    codes: jnp.ndarray,    # (N, d/8) uint8
+    q_planes: jnp.ndarray,  # (8, B, d/8) float: plane j = q[:, j::8]
+    codes: jnp.ndarray,     # (N, d/8) uint8
     bq: int = DEFAULT_BQ,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    B, d = q.shape
-    N, d8 = codes.shape
-    assert d == d8 * 8
+    _, B, d8 = q_planes.shape
+    N, d8c = codes.shape
+    assert d8 == d8c
     assert B % bq == 0 and N % bn == 0, "caller (ops.py) pads to tile multiples"
 
     grid = (B // bq, N // bn)
@@ -57,10 +67,10 @@ def binary_ip_pallas(
         _binary_ip_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
+            pl.BlockSpec((8, bq, d8), lambda i, j: (0, i, 0)),
             pl.BlockSpec((bn, d8), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        interpret=interpret,
-    )(q, codes)
+        interpret=interpret_mode(interpret),
+    )(q_planes, codes)

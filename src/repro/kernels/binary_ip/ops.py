@@ -20,14 +20,23 @@ def _pad_to(x: jnp.ndarray, axis: int, multiple: int):
     return jnp.pad(x, pad), size
 
 
+def bit_planes(q: jnp.ndarray) -> jnp.ndarray:
+    """(B, d) -> (8, B, d/8): plane j holds columns j, 8+j, 16+j, ... — the
+    dimensions stored in bit j of each code byte (little-endian packbits)."""
+    B, d = q.shape
+    return q.reshape(B, d // 8, 8).transpose(2, 0, 1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def binary_ip(q: jnp.ndarray, codes: jnp.ndarray, interpret: bool = True) -> jnp.ndarray:
+def binary_ip(
+    q: jnp.ndarray, codes: jnp.ndarray, interpret: bool | None = None
+) -> jnp.ndarray:
     """<q_b, sign_n> (B, N) via the Pallas kernel, any B/N (auto-padded)."""
     bq = min(_k.DEFAULT_BQ, max(8, q.shape[0]))
     bn = min(_k.DEFAULT_BN, max(8, codes.shape[0]))
     qp, B = _pad_to(q, 0, bq)
     cp, N = _pad_to(codes, 0, bn)
-    out = _k.binary_ip_pallas(qp, cp, bq=bq, bn=bn, interpret=interpret)
+    out = _k.binary_ip_pallas(bit_planes(qp), cp, bq=bq, bn=bn, interpret=interpret)
     return out[:B, :N]
 
 
@@ -37,7 +46,7 @@ def estimate_dist2(
     codes: jnp.ndarray,    # (N, d/8) uint8
     norms: jnp.ndarray,    # (N,)
     ip_bar: jnp.ndarray,   # (N,)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """RaBitQ level-1 estimated squared distances (B, N).
 

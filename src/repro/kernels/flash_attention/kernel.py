@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 DEFAULT_BQ = 128
 DEFAULT_BK = 128
@@ -104,7 +106,7 @@ def flash_attention_pallas(
     kv_valid: int | None = None,   # default: all keys valid
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     B, H, Sq, Dh = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
@@ -135,5 +137,5 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, 1), jnp.float32),    # running denom l
             pltpu.VMEM((bq, Dh), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

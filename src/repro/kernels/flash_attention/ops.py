@@ -1,6 +1,6 @@
 """Public wrapper: pads sequence lengths to tile multiples and dispatches.
 
-The models call this for prefill; interpret=True on CPU (oracle-validated),
+The models call this for prefill; interpret mode on CPU (oracle-validated),
 compiled pallas on TPU.  Padding policy:
   causal:     pad queries at the FRONT, keys at the BACK; real query i keeps
               position i + (Skv - Sq) via an explicit offset, padded keys are
@@ -29,7 +29,7 @@ def flash_attention(
     causal: bool = True,
     window: int | None = None,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     B, H, Sq, Dh = q.shape
     Skv = k.shape[2]

@@ -18,13 +18,20 @@ def _pad_rows(x: jnp.ndarray, multiple: int):
     return jnp.pad(x, pad)
 
 
+def nibble_halves(q: jnp.ndarray) -> jnp.ndarray:
+    """(B, d) -> (2, B, d/2): the even columns (low nibbles) and the odd
+    columns (high nibbles) of the query."""
+    B, d = q.shape
+    return q.reshape(B, d // 2, 2).transpose(2, 0, 1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def int4_dist2(
     q: jnp.ndarray,        # (B, d)
     codes: jnp.ndarray,    # (N, d/2) uint8
     lo: jnp.ndarray,       # (N,)
     step: jnp.ndarray,     # (N,)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Refined squared distances (B, N) from packed 4-bit codes."""
     B, N = q.shape[0], codes.shape[0]
@@ -37,5 +44,7 @@ def int4_dist2(
     stepp = jnp.pad(
         step.reshape(-1, 1), [(0, (-N) % bn), (0, 0)], constant_values=1.0
     )
-    out = _k.int4_dist_pallas(qp, cp, lop, stepp, bq=bq, bn=bn, interpret=interpret)
+    out = _k.int4_dist_pallas(
+        nibble_halves(qp), cp, lop, stepp, bq=bq, bn=bn, interpret=interpret
+    )
     return out[:B, :N]

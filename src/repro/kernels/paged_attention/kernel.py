@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_mode
+
 
 NEG_INF = -1e30
 
@@ -85,7 +87,7 @@ def paged_attention_pallas(
     block_tables: jnp.ndarray,  # (B, max_pages) int32
     context_lens: jnp.ndarray,  # (B,) int32
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     B, H, Dh = q.shape
     P, page, KVH, _ = k_pages.shape
@@ -124,5 +126,5 @@ def paged_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables, context_lens, q, k_pages, v_pages)

@@ -18,7 +18,7 @@ def paged_attention(
     block_tables: jnp.ndarray,  # (B, max_pages) int32
     context_lens: jnp.ndarray,  # (B,) int32
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     return _k.paged_attention_pallas(
         q, k_pages, v_pages,

@@ -2,9 +2,12 @@
 
 Three terms per (arch x shape x mesh) cell, all in seconds per step:
 
-  compute    = HLO_dot_FLOPs_per_device / 197e12        (v5e bf16 peak)
-  memory     = HLO_bytes_per_device     / 819e9         (HBM bandwidth)
-  collective = collective_bytes_per_device / 50e9       (ICI per link)
+  compute    = HLO_dot_FLOPs_per_device / peak bf16 FLOP/s
+  memory     = HLO_bytes_per_device     / peak HBM bytes/s
+  collective = collective_bytes_per_device / ICI bytes/s per link
+
+with the peaks of the cell's chip from ``PEAKS``, keyed by JAX's
+``device_kind``.  The dry-run meshes are TPU v5e pods.
 
 FLOPs and collective bytes are trip-count-corrected (hlo_analysis).  The
 memory term uses fusion-boundary traffic of the CPU-backend HLO — an UPPER
@@ -24,9 +27,30 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12       # bf16 per chip (TPU v5e)
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link
+# Published per-chip peaks, keyed by ``jax.devices()[i].device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip
+# interconnect (200 GB/s over 4 links, 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12, "int8_ops": 393e12, "hbm_bytes": 16 * 2**30,
+        "hbm_bw": 819e9, "ici_link_bw": 50e9,
+    },
+}
+
+DRYRUN_DEVICE_KIND = "TPU v5 lite"  # the chip the dry-run meshes describe
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; an unknown device is an
+    error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
+
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out", "dryrun")
 
@@ -67,9 +91,10 @@ def analyze(rec: dict) -> dict:
                 "reason": rec.get("reason", rec.get("error", ""))[:90]}
     c = rec["cost"]
     coll = rec["collectives"]
-    t_compute = c["flops_per_device"] / PEAK_FLOPS
-    t_memory = c["bytes_accessed_per_device"] / HBM_BW
-    t_coll = coll["collective_bytes_per_device"] / ICI_BW
+    pk = peaks(rec.get("device_kind", DRYRUN_DEVICE_KIND))
+    t_compute = c["flops_per_device"] / pk["bf16_flops"]
+    t_memory = c["bytes_accessed_per_device"] / pk["hbm_bw"]
+    t_coll = coll["collective_bytes_per_device"] / pk["ici_link_bw"]
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(rec)
@@ -84,7 +109,7 @@ def analyze(rec: dict) -> dict:
         "model_flops_per_device": mf,
         "useful_flops_ratio": useful,
         "mem_per_device_gib": rec["memory"]["peak_estimate_bytes"] / 2**30,
-        "fits_hbm_16g": rec["memory"]["peak_estimate_bytes"] < 16 * 2**30,
+        "fits_hbm_16g": rec["memory"]["peak_estimate_bytes"] < pk["hbm_bytes"],
     }
 
 

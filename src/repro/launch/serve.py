@@ -2,9 +2,13 @@
 
 Builds the compressed index over a synthetic corpus, then pushes a batched
 query stream through the asynchronous engine and reports the paper's
-metrics (QPS / latency / recall / IO / hit rate).
+metrics (QPS / latency / recall / IO / hit rate).  QPS and latency are the
+engine's modelled seconds, not device time.
 
   PYTHONPATH=src python -m repro.launch.serve --n 20000 --d 128 --queries 500
+
+The device path: ``--backend pallas --device-beam --hbm-tier --fuse
+--shared-rendezvous`` (compiled kernels on the TPU, interpret mode on CPU).
 """
 
 from __future__ import annotations
@@ -12,11 +16,12 @@ from __future__ import annotations
 import argparse
 import time
 
-from repro.core import baselines, dataset, vamana
+from repro.core import baselines, dataset, distance, vamana
 from repro.core.quant import RabitQuantizer
+from repro.launch import compile_cache
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=10000)
     ap.add_argument("--d", type=int, default=64)
@@ -28,8 +33,22 @@ def main(argv=None):
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--L", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    ap.add_argument("--backend", default="default",
+                    choices=list(distance.BACKENDS) + ["default"],
+                    help="distance engine (default: the process default, batch)")
+    ap.add_argument("--device-beam", action="store_true",
+                    help="fused on-device beam step")
+    ap.add_argument("--hbm-tier", action="store_true",
+                    help="device-resident record tier above the host pool")
+    ap.add_argument("--fuse", action="store_true",
+                    help="cross-query fused score dispatch")
+    ap.add_argument("--shared-rendezvous", action="store_true",
+                    help="one rendezvous buffer spanning all workers")
+    return ap.parse_args(argv)
 
+
+def build_index(args: argparse.Namespace):
+    """Corpus, Vamana graph and quantized base for ``args``, from its seed."""
     t0 = time.time()
     print(f"[serve] generating corpus n={args.n} d={args.d} ...", flush=True)
     ds = dataset.make_dataset(n=args.n, d=args.d, n_queries=args.queries,
@@ -37,20 +56,37 @@ def main(argv=None):
     print(f"[serve] building Vamana graph ... ({time.time()-t0:.1f}s)", flush=True)
     graph = vamana.build_vamana(ds.base, R=32, L=64, seed=args.seed)
     qb = RabitQuantizer(args.d, seed=args.seed).fit_encode(ds.base)
-    print(f"[serve] index built ({time.time()-t0:.1f}s); running {args.system} ...",
-          flush=True)
+    print(f"[serve] index built ({time.time()-t0:.1f}s)", flush=True)
+    return ds, graph, qb
+
+
+def main(argv=None, index=None):
+    """Serve the query stream once; returns ``baselines.evaluate``'s record.
+    ``index`` is a ``build_index`` result for the same --n/--d/--queries/
+    --seed, to serve one index under several configurations."""
+    args = parse_args(argv)
+    compile_cache.enable()
+    ds, graph, qb = index if index is not None else build_index(args)
+    print(f"[serve] running {args.system} ...", flush=True)
 
     cfg = baselines.SystemConfig(
         buffer_ratio=args.buffer_ratio, batch_size=args.batch,
         n_workers=args.workers,
         params=baselines.SearchParams(L=args.L, W=4),
+        seed=args.seed,
+        distance_backend=args.backend,
+        device_beam=args.device_beam or None,
+        hbm_tier=args.hbm_tier or None,
+        fuse=args.fuse or None,
+        shared_rendezvous=args.shared_rendezvous or None,
     )
     system = baselines.build_system(args.system, ds.base, graph, qb, cfg)
     out = baselines.evaluate(system, ds)
-    print(f"[serve] system={out['system']} recall@10={out['recall@k']:.3f} "
+    print(f"[serve] system={out['system']} backend={out['distance_backend']} "
+          f"recall@10={out['recall@k']:.3f} io/q={out['ios_per_query']:.1f} "
+          f"hit={out['hit_rate']:.2f} | modelled, not device time: "
           f"QPS={out['qps']:.0f} mean_lat={out['mean_latency_ms']:.2f}ms "
-          f"p99={out['p99_latency_ms']:.2f}ms io/q={out['ios_per_query']:.1f} "
-          f"hit={out['hit_rate']:.2f}")
+          f"p99={out['p99_latency_ms']:.2f}ms")
     print(f"[serve] disk={out['disk_bytes']/1e6:.1f}MB "
           f"memory={out['memory_bytes']/1e6:.1f}MB "
           f"(origin {ds.base.nbytes/1e6:.1f}MB)")

@@ -22,7 +22,8 @@ from repro.velo import scan_search as ss
 from repro.velo.index import DeviceIndex
 
 
-def local_search_fn(mode: str, L: int, k: int, max_steps: int, interpret: bool):
+def local_search_fn(mode: str, L: int, k: int, max_steps: int,
+                    interpret: bool | None):
     if mode == "scan":
         def run(index, queries):
             ids, d2 = ss.scan_search(index, queries, k=k, rerank=L, interpret=interpret)
@@ -76,7 +77,7 @@ def make_distributed_search(
     L: int = 64,
     k: int = 10,
     max_steps: int = 96,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Builds a shard_map'd search: (sharded DeviceIndex, shard_offsets,
     replicated queries) -> (global ids (B, k), dist2 (B, k))."""

@@ -42,11 +42,16 @@ class DeviceIndex:
         return self.adjacency.shape[1]
 
 
-def from_host(qb, graph) -> DeviceIndex:
-    """Build the device pytree from host-plane artifacts (QuantizedBase + VamanaGraph)."""
+def from_host(qb, graph=None) -> DeviceIndex:
+    """Build the device pytree from host-plane artifacts (QuantizedBase +
+    VamanaGraph).  Scan mode reads no adjacency: with ``graph=None`` every
+    row gets one sentinel neighbour and the medoid is row 0."""
     n = qb.norms.shape[0]
-    adj = graph.adjacency.copy()
-    adj[adj < 0] = n  # sentinel
+    if graph is None:
+        adj, medoid = np.full((n, 1), n, dtype=np.int32), 0
+    else:
+        adj, medoid = graph.adjacency.copy(), graph.medoid
+        adj[adj < 0] = n  # sentinel
     sent_adj = np.full((1, adj.shape[1]), n, dtype=np.int32)
     big = np.float32(1e30)
     return DeviceIndex(
@@ -63,7 +68,7 @@ def from_host(qb, graph) -> DeviceIndex:
         ext_lo=jnp.asarray(np.concatenate([qb.ext_lo, [0.0]]).astype(np.float32)),
         ext_step=jnp.asarray(np.concatenate([qb.ext_step, [1.0]]).astype(np.float32)),
         adjacency=jnp.asarray(np.concatenate([adj, sent_adj])),
-        medoid=jnp.asarray(graph.medoid, dtype=jnp.int32),
+        medoid=jnp.asarray(medoid, dtype=jnp.int32),
     )
 
 

@@ -38,7 +38,7 @@ def scan_search(
     queries: jnp.ndarray,     # (B, d)
     k: int = 10,
     rerank: int = 64,         # candidates refined in stage 2 (C)
-    interpret: bool = True,
+    interpret: bool | None = None,
     use_kernel: bool = True,  # False: pure-jnp GEMM (dry-run lowering path —
                               # interpret-mode Pallas would unroll the grid
                               # into the HLO; on real TPUs use_kernel=True)

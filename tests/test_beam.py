@@ -77,11 +77,6 @@ def _recall(results, ds):
     return dataset_mod.recall_at_k(ids, ds.groundtruth, 10)
 
 
-def _skip_unless_available(backend):
-    if backend == "pallas" and not distance_mod.pallas_available():
-        pytest.skip("pallas backend unavailable (no jax)")
-
-
 # ------------------------------------------------- the host-parity contract
 
 
@@ -89,7 +84,6 @@ def _skip_unless_available(backend):
 @pytest.mark.parametrize("backend", BACKENDS)
 @pytest.mark.parametrize("algo", ALGOS)
 def test_device_beam_bitwise_parity_with_host(algo, backend, fuse, tiny):
-    _skip_unless_available(backend)
     _, ref, _ = _run(tiny, algo, False, fuse, backend)
     _, got, stats = _run(tiny, algo, True, fuse, backend)
     with_hops = not (fuse and algo in TIMING_DEPENDENT)
@@ -176,7 +170,6 @@ def test_beam_step_backends_match_scalar_oracle(backend):
     to float32 rounding only (scalar vs vectorized accumulation order);
     the bitwise contract is host-vs-device WITHIN a backend, asserted by
     the system-level parity tests above."""
-    _skip_unless_available(backend)
     rng = np.random.default_rng(3)
     n, d, L = 200, 16, 8
     base = rng.standard_normal((n, d)).astype(np.float32)

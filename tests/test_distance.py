@@ -202,8 +202,11 @@ def test_get_engine_selection_rules():
         distance.get_engine("not-a-backend")
     with pytest.raises(ValueError):
         distance.set_default_backend("not-a-backend")
-    # auto: pallas when jax is importable, batch otherwise — never an error
-    assert distance.get_engine("auto").name in ("pallas", "batch")
+    # no silent substitute: the device engine is what "pallas" serves, and
+    # an alias that could resolve elsewhere does not exist
+    assert distance.get_engine("pallas").name == "pallas"
+    with pytest.raises(ValueError):
+        distance.get_engine("auto")
 
 
 def test_search_context_defaults_to_process_backend(small_ds, small_graph, small_qb):

@@ -61,6 +61,23 @@ def test_shape_bytes():
     assert H._shape_bytes("pred[10]") == 10
 
 
+def test_roofline_peaks_keyed_by_device_kind():
+    from repro.launch import roofline
+
+    pk = roofline.peaks("TPU v5 lite")
+    assert pk["bf16_flops"] == 197e12 and pk["hbm_bw"] == 819e9
+    with pytest.raises(KeyError):
+        roofline.peaks("cpu")
+    ok = {"status": "ok", "arch": "a", "shape": "s", "multi_pod": False,
+          "n_devices": 1,
+          "cost": {"flops_per_device": 197e12, "bytes_accessed_per_device": 0},
+          "collectives": {"collective_bytes_per_device": 0},
+          "memory": {"peak_estimate_bytes": 1}}
+    assert roofline.analyze(ok)["t_compute_s"] == pytest.approx(1.0)
+    with pytest.raises(KeyError):
+        roofline.analyze(dict(ok, device_kind="TPU v9 unknown"))
+
+
 @pytest.mark.slow
 def test_dryrun_cell_subprocess(tmp_path):
     """Lower+compile one real production cell at 512 virtual devices."""

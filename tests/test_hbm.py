@@ -79,8 +79,6 @@ def test_lookup_roundtrip_bit_identity(small_qb):
 
 @pytest.mark.parametrize("backend", ["scalar", "batch", "pallas"])
 def test_refine_slots_matches_refine_ids(small_ds, small_qb, backend):
-    if backend == "pallas" and not distance_mod.pallas_available():
-        pytest.skip("pallas backend unavailable")
     eng = distance_mod.get_engine(backend)
     vids = np.asarray([2, 9, 17, 30, 41], dtype=np.int64)
     tier = _tier_with(small_qb, vids)
@@ -101,11 +99,8 @@ def test_refine_slots_matches_refine_ids(small_ds, small_qb, backend):
 
 
 def test_pallas_slot_gather_zero_upload(small_ds, small_qb):
-    if not distance_mod.pallas_available():
-        pytest.skip("pallas backend unavailable")
     eng = distance_mod.get_engine("pallas")
-    if eng.name != "pallas" or not eng.resident:
-        pytest.skip("pallas resident plane unavailable")
+    assert eng.name == "pallas" and eng.resident
     vids = np.asarray([1, 5, 9], dtype=np.int64)
     tier = _tier_with(small_qb, vids)
     slots = tier.cache.record_map[vids].astype(np.int64)

@@ -23,7 +23,8 @@ RNG = np.random.default_rng(7)
 
 
 @pytest.mark.parametrize("B,N,d", [(1, 1, 8), (4, 10, 64), (128, 256, 128),
-                                   (33, 777, 256), (5, 64, 1024)])
+                                   (33, 777, 256), (5, 64, 1024),
+                                   (8, 300, 480), (16, 512, 960)])
 def test_binary_ip_matches_ref(B, N, d):
     q = RNG.standard_normal((B, d)).astype(np.float32)
     codes = RNG.integers(0, 256, size=(N, d // 8)).astype(np.uint8)
@@ -39,6 +40,20 @@ def test_binary_ip_dtypes(dtype):
     out = binary_ip(q, codes)
     ref = binary_ip_ref(jnp.asarray(q, jnp.float32), codes)
     np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-1)
+
+
+@pytest.mark.parametrize("B,N,d", [(8, 300, 480), (16, 512, 960)])
+def test_estimate_dist2_matches_ref_wide(B, N, d):
+    """GIST-width level-1 estimates: the bit-plane kernel against the
+    unpack-then-GEMM oracle."""
+    q = RNG.standard_normal((B, d)).astype(np.float32)
+    codes = RNG.integers(0, 256, size=(N, d // 8)).astype(np.uint8)
+    norms = RNG.uniform(0.5, 2.0, N).astype(np.float32)
+    ip_bar = RNG.uniform(0.6, 0.9, N).astype(np.float32)
+    np.testing.assert_allclose(
+        estimate_dist2(q, codes, norms, ip_bar),
+        estimate_dist2_ref(q, codes, norms, ip_bar), rtol=1e-4, atol=1e-3,
+    )
 
 
 def test_estimate_matches_host_quantizer(small_ds, small_qb):
@@ -62,7 +77,8 @@ def test_estimate_matches_host_quantizer(small_ds, small_qb):
 # ------------------------------------------------------------------ int4_dist
 
 
-@pytest.mark.parametrize("B,N,d", [(1, 1, 8), (3, 7, 64), (64, 200, 128), (16, 512, 960)])
+@pytest.mark.parametrize("B,N,d", [(1, 1, 8), (3, 7, 64), (64, 200, 128),
+                                   (8, 300, 480), (16, 512, 960)])
 def test_int4_dist_matches_ref(B, N, d):
     d = d + (d % 2)
     q = RNG.standard_normal((B, d)).astype(np.float32)
