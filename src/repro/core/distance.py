@@ -49,6 +49,7 @@ import dataclasses
 import numpy as np
 
 from repro.core import beam as beam_mod
+from repro.core.spans import span
 from repro.core.quant import (
     PreparedQuery,
     QuantizedBase,
@@ -796,15 +797,17 @@ def _pallas_resident_fns():
         @functools.partial(jax.jit, static_argnames=("interpret",))
         def gather_estimate(q, codes, norms, ip_bar, ids, interpret):
             # the gather happens where the table lives: on the device
-            return _binary_est(
-                q, codes[ids], norms[ids], ip_bar[ids], interpret=interpret
-            )
+            with jax.named_scope("velo.dist.gather_estimate"):
+                return _binary_est(
+                    q, codes[ids], norms[ids], ip_bar[ids], interpret=interpret
+                )
 
         @functools.partial(jax.jit, static_argnames=("interpret",))
         def gather_refine(q, codes, lo, step, ids, interpret):
-            return _int4_dist2(
-                q, codes[ids], lo[ids], step[ids], interpret=interpret
-            )
+            with jax.named_scope("velo.dist.gather_refine"):
+                return _int4_dist2(
+                    q, codes[ids], lo[ids], step[ids], interpret=interpret
+                )
 
         _PALLAS_RESIDENT_FNS = (gather_estimate, gather_refine)
     return _PALLAS_RESIDENT_FNS
@@ -828,9 +831,13 @@ def _pallas_beam_fn():
         from repro.kernels.binary_ip import estimate_dist2 as _binary_est
 
         @functools.partial(jax.jit, static_argnames=("bucket", "interpret"))
-        def beam_step(Q, codes, norms, ip_bar, ids, vid_base, fresh_len,
-                      ins_v, ins_d, ins_len, expl, cand_d, cand_v, visited,
-                      explored, bucket, interpret):
+        def beam_step(*args, bucket, interpret):
+            with jax.named_scope("velo.dist.beam_step"):
+                return _beam_step(*args, bucket=bucket, interpret=interpret)
+
+        def _beam_step(Q, codes, norms, ip_bar, ids, vid_base, fresh_len,
+                       ins_v, ins_d, ins_len, expl, cand_d, cand_v, visited,
+                       explored, bucket, interpret):
             B, Fp = ids.shape
             L = cand_d.shape[1]
             sink = visited.shape[1] - 1  # pad-lane write target (slot n)
@@ -930,6 +937,12 @@ class _DeviceTable:
         return self.host.gather_level2(ids)
 
 
+def _fetch(x, dtype=None) -> np.ndarray:
+    """A blocking device->host read of a result."""
+    with span("velo.dist.fetch"):
+        return np.asarray(x, dtype=dtype)
+
+
 class PallasEngine(BatchEngine):
     """JAX/Pallas kernels for both quantized levels.
 
@@ -1003,7 +1016,7 @@ class PallasEngine(BatchEngine):
             pq.qr[None, :], tbl.binary_codes, tbl.norms, tbl.ip_bar, idsp,
             interpret=self.interpret,
         )
-        return np.asarray(out[0, :m], dtype=np.float32)
+        return _fetch(out[0, :m], np.float32)
 
     def _refine_ids(self, qb, tbl, pq, ids):
         if not self.resident or qb.ext_bits != 4:
@@ -1015,7 +1028,7 @@ class PallasEngine(BatchEngine):
             pq.qr[None, :], tbl.ext_codes, tbl.ext_lo, tbl.ext_step, idsp,
             interpret=self.interpret,
         )
-        return np.asarray(out[0, :m], dtype=np.float32)
+        return _fetch(out[0, :m], np.float32)
 
     def _estimate_ids_many(self, qb, tbl, pqs, sizes, ids):
         if not self.resident:
@@ -1023,7 +1036,7 @@ class PallasEngine(BatchEngine):
         gather_est, _ = _pallas_resident_fns()
         m, idsp = self._pad_ids(ids)
         Q = np.stack([pq.qr for pq in pqs])  # (B, d)
-        out = np.asarray(gather_est(
+        out = _fetch(gather_est(
             Q, tbl.binary_codes, tbl.norms, tbl.ip_bar, idsp,
             interpret=self.interpret,
         ))  # (B, mp)
@@ -1036,7 +1049,7 @@ class PallasEngine(BatchEngine):
         _, gather_ref = _pallas_resident_fns()
         m, idsp = self._pad_ids(ids)
         Q = np.stack([pq.qr for pq in pqs])  # (B, d)
-        out = np.asarray(gather_ref(
+        out = _fetch(gather_ref(
             Q, tbl.ext_codes, tbl.ext_lo, tbl.ext_step, idsp,
             interpret=self.interpret,
         ))  # (B, mp)
@@ -1057,7 +1070,7 @@ class PallasEngine(BatchEngine):
         out = gather_ref(
             pq.qr[None, :], ext, lo, step, slotsp, interpret=self.interpret
         )
-        return np.asarray(out[0, :m], dtype=np.float32)
+        return _fetch(out[0, :m], np.float32)
 
     def _refine_slots_many(self, view, pqs, sizes, slots):
         if not self.resident or view.qb.ext_bits != 4:
@@ -1066,7 +1079,7 @@ class PallasEngine(BatchEngine):
         ext, lo, step = view.device_arrays()
         m, slotsp = self._pad_ids(slots)
         Q = np.stack([pq.qr for pq in pqs])  # (B, d)
-        out = np.asarray(gather_ref(
+        out = _fetch(gather_ref(
             Q, ext, lo, step, slotsp, interpret=self.interpret,
         ))  # (B, mp)
         owner = np.repeat(np.arange(len(pqs)), sizes)
@@ -1094,14 +1107,15 @@ class PallasEngine(BatchEngine):
     def _beam_host_view(self, st):
         if st.backend != "device":
             return super()._beam_host_view(st)
-        return (
-            np.asarray(st.cand_d),
-            np.asarray(st.cand_v, dtype=np.int64),
-            # masks are mutated in place by the generic path; device->host
-            # views are read-only, so materialize writable copies
-            np.array(st.visited),
-            np.array(st.explored),
-        )
+        with span("velo.dist.fetch"):
+            return (
+                np.asarray(st.cand_d),
+                np.asarray(st.cand_v, dtype=np.int64),
+                # masks are mutated in place by the generic path; device->host
+                # views are read-only, so materialize writable copies
+                np.array(st.visited),
+                np.array(st.explored),
+            )
 
     def _beam_store(self, st, cand_d, cand_v, visited, explored):
         if st.backend != "device":
@@ -1173,9 +1187,10 @@ class PallasEngine(BatchEngine):
             bucket=self.bucket, interpret=self.interpret,
         )
         # the ONE host<->device exchange per step: frontiers + two scalars
-        frontier_np = np.asarray(frontier)
-        wlen_np = np.asarray(wlen)
-        tail_np = np.asarray(tail)
+        with span("velo.dist.fetch"):
+            frontier_np = np.asarray(frontier)
+            wlen_np = np.asarray(wlen)
+            tail_np = np.asarray(tail)
         out = []
         for i, r in enumerate(reqs):
             r.state.cand_d = cand_d[i]
@@ -1200,7 +1215,7 @@ class PallasEngine(BatchEngine):
         out = self._binary_est(
             pq.qr[None, :], codes, norms, ip_bar, interpret=self.interpret
         )
-        return np.asarray(out[0, :m], dtype=np.float32)
+        return _fetch(out[0, :m], np.float32)
 
     def _refine(self, qb, pq, codes, lo, step):
         if qb.ext_bits != 4:  # the kernel is nibble-packed int4 only
@@ -1210,7 +1225,7 @@ class PallasEngine(BatchEngine):
         out = self._int4_dist2(
             pq.qr[None, :], codes, lo, step, interpret=self.interpret
         )
-        return np.asarray(out[0, :m], dtype=np.float32)
+        return _fetch(out[0, :m], np.float32)
 
     # ---- fused multi-query paths: the kernels are (B, N)-shaped already ----
 
@@ -1220,7 +1235,7 @@ class PallasEngine(BatchEngine):
         )
         self.stats.uploads += 1
         Q = np.stack([pq.qr for pq in pqs])  # (B, d)
-        out = np.asarray(
+        out = _fetch(
             self._binary_est(Q, codes, norms, ip_bar, interpret=self.interpret)
         )  # (B, mp)
         owner = np.repeat(np.arange(len(pqs)), sizes)
@@ -1232,7 +1247,7 @@ class PallasEngine(BatchEngine):
         m, (codes, lo, step) = self._pad_to_bucket([codes, lo, step], [0, 0, 1])
         self.stats.uploads += 1
         Q = np.stack([pq.qr for pq in pqs])  # (B, d)
-        out = np.asarray(
+        out = _fetch(
             self._int4_dist2(Q, codes, lo, step, interpret=self.interpret)
         )  # (B, mp)
         owner = np.repeat(np.arange(len(pqs)), sizes)
@@ -1320,31 +1335,33 @@ def execute_requests(
                 "QuantizedBase: set ScoreRequest.qb or pass qb= to the "
                 "Engine / run_workload executing these coroutines"
             )
-        if kind == "beam":
-            res = engine.beam_step_many(gqb, [reqs[i] for i in idxs])
-        elif kind == "beam_part":
-            res = engine.beam_score_local_many(gqb, [reqs[i] for i in idxs])
-        elif kind == "estimate":
-            res = engine.estimate_many(
-                gqb, [(reqs[i].pq, reqs[i].payload) for i in idxs]
-            )
-        elif kind == "refine":
-            if splits and any(id(reqs[i]) in splits for i in idxs):
-                res = _execute_refine_split(engine, gqb, hbm, reqs, idxs, splits)
-            else:
-                res = engine.refine_ids_many(
+        rows = sum(reqs[i].rows for i in idxs)
+        with span("velo.dist.call", kind=kind, rows=rows):
+            if kind == "beam":
+                res = engine.beam_step_many(gqb, [reqs[i] for i in idxs])
+            elif kind == "beam_part":
+                res = engine.beam_score_local_many(gqb, [reqs[i] for i in idxs])
+            elif kind == "estimate":
+                res = engine.estimate_many(
                     gqb, [(reqs[i].pq, reqs[i].payload) for i in idxs]
                 )
-        elif kind == "refine_rows":
-            res = engine.refine_many(
-                gqb, [(reqs[i].pq, *reqs[i].payload) for i in idxs]
-            )
-        elif kind == "full":
-            res = engine.refine_full_many(
-                [(reqs[i].query, reqs[i].payload) for i in idxs]
-            )
-        else:
-            raise ValueError(f"unknown score request kind {kind!r}")
+            elif kind == "refine":
+                if splits and any(id(reqs[i]) in splits for i in idxs):
+                    res = _execute_refine_split(engine, gqb, hbm, reqs, idxs, splits)
+                else:
+                    res = engine.refine_ids_many(
+                        gqb, [(reqs[i].pq, reqs[i].payload) for i in idxs]
+                    )
+            elif kind == "refine_rows":
+                res = engine.refine_many(
+                    gqb, [(reqs[i].pq, *reqs[i].payload) for i in idxs]
+                )
+            elif kind == "full":
+                res = engine.refine_full_many(
+                    [(reqs[i].query, reqs[i].payload) for i in idxs]
+                )
+            else:
+                raise ValueError(f"unknown score request kind {kind!r}")
         for i, r_ in zip(idxs, res):
             out[i] = r_
     return out

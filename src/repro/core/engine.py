@@ -132,6 +132,7 @@ from repro.core import beam as beam_mod
 from repro.core import distance as distance_mod
 from repro.core.scheduling import SCHEDULERS
 from repro.core.sim import SSD, CostModel, WorkloadStats
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -221,6 +222,10 @@ class Engine:
                                     # latency == service time, bitwise the
                                     # pre-SLA engine)
     ) -> tuple[list, WorkloadStats]:
+        with span("velo.engine.run", queries=len(queries)):
+            return self._run(make_coroutine, queries, sla)
+
+    def _run(self, make_coroutine, queries, sla) -> tuple[list, WorkloadStats]:
         cfg = self.config
         assert cfg.scheduler in SCHEDULERS, f"unknown scheduler {cfg.scheduler!r}"
         if self.dist is None:
@@ -305,7 +310,6 @@ class Engine:
             inflight[pid] = comp
             heapq.heappush(inflight_heap, (comp, pid))
             stats.io_count += 1
-            stats.io_bytes += cfg.page_size
             return comp, t
 
         def drop_query_tokens(qid: int) -> None:
@@ -507,26 +511,27 @@ class Engine:
             ``rebates`` accumulates, per dispatch group, the simulated seconds
             the slot-gather saves over the registered-table refine (hit rows
             are charged ``hbm_refine_ext`` instead of ``refine_ext``)."""
-            splits: dict[int, tuple] = {}
-            rebates: dict[tuple, float] = {}
-            for r in reqs:
-                if r.kind != "refine" or isinstance(r.payload, tuple):
-                    continue
-                rqb = r.qb if r.qb is not None else self.qb
-                if rqb is None or not self.hbm.covers(rqb):
-                    continue
-                sp = self.hbm.peek_split(np.asarray(r.payload, dtype=np.int64))
-                if sp is None:
-                    continue
-                mask, slots = sp
-                splits[id(r)] = (mask, slots)
-                key = distance_mod.request_group_key(r, self.qb)
-                per_row = max(
-                    0.0,
-                    self.cost.refine_ext(rqb.dim)
-                    - self.cost.hbm_refine_ext(rqb.dim),
-                )
-                rebates[key] = rebates.get(key, 0.0) + per_row * int(mask.sum())
+            with span("velo.cache.hbm"):
+                splits: dict[int, tuple] = {}
+                rebates: dict[tuple, float] = {}
+                for r in reqs:
+                    if r.kind != "refine" or isinstance(r.payload, tuple):
+                        continue
+                    rqb = r.qb if r.qb is not None else self.qb
+                    if rqb is None or not self.hbm.covers(rqb):
+                        continue
+                    sp = self.hbm.peek_split(np.asarray(r.payload, dtype=np.int64))
+                    if sp is None:
+                        continue
+                    mask, slots = sp
+                    splits[id(r)] = (mask, slots)
+                    key = distance_mod.request_group_key(r, self.qb)
+                    per_row = max(
+                        0.0,
+                        self.cost.refine_ext(rqb.dim)
+                        - self.cost.hbm_refine_ext(rqb.dim),
+                    )
+                    rebates[key] = rebates.get(key, 0.0) + per_row * int(mask.sum())
             return splits, rebates
 
         def dispatch_batch(initiator: _Worker, reqs: list) -> list:
@@ -545,58 +550,69 @@ class Engine:
             this flush's fused dispatch: only ``hbm_scatter_s`` net of the
             dispatch time is charged (double buffering — compute step t hides
             the installs for step t+1)."""
-            charge_upload(initiator, reqs)
-            splits = rebates = None
-            if self.hbm is not None:
-                splits, rebates = hbm_split(reqs)
-            flop_by_group: dict[tuple, float] = {}
-            tenants_by_group: dict[tuple, set] = {}
-            for r in reqs:
-                key = distance_mod.request_group_key(r, self.qb)
-                flop_by_group[key] = flop_by_group.get(key, 0.0) + r.flop_s
-                tenants_by_group.setdefault(key, set()).add(r.tenant)
-            dispatch_s = 0.0
-            for key, flop_s in flop_by_group.items():
-                if rebates:
-                    flop_s = max(0.0, flop_s - rebates.get(key, 0.0))
-                d = self.cost.fused_batch_s(flop_s, kind=key[0])
-                initiator.t += d
-                dispatch_s += d
-            outs = distance_mod.execute_requests(
-                self.dist, self.qb, reqs, hbm=self.hbm, splits=splits
-            )
-            stats.score_flushes += len(flop_by_group)
-            stats.score_requests += len(reqs)
-            stats.score_rows += sum(r.rows for r in reqs)
-            n_beam = sum(
-                1 for r in reqs if isinstance(r, beam_mod.BeamRequest)
-            )
-            stats.beam_ops += n_beam
-            stats.beam_rows += sum(
-                r.rows for r in reqs if isinstance(r, beam_mod.BeamRequest)
-            )
-            stats.beam_flushes += sum(
-                1 for key in flop_by_group if key[0].startswith("beam")
-            )
-            # beam replies ship a frontier, not distances — everything else
-            # in the flush still downloads its raw per-row result
-            stats.dist_downloads += len(reqs) - n_beam
-            # cross-tenant FUSION means one dispatch group genuinely spanned
-            # tenants — a flush whose per-tenant requests were routed to
-            # separate per-table calls does not count
-            if any(len(ts) > 1 for ts in tenants_by_group.values()):
-                stats.cross_tenant_flushes += 1
-            if self.hbm is not None:
-                n_scattered = self.hbm.scatter_staged()
-                if n_scattered:
-                    initiator.t += max(
-                        0.0, self.cost.hbm_scatter_s - dispatch_s
-                    )
-                    if sched is not None:
-                        sched.note(("scatter", n_scattered))
-            if verify is not None:
-                verify.at_flush()
+            rows = sum(r.rows for r in reqs)
+            with span("velo.engine.flush", requests=len(reqs), rows=rows):
+                charge_upload(initiator, reqs)
+                splits = rebates = None
+                if self.hbm is not None:
+                    splits, rebates = hbm_split(reqs)
+                flop_by_group: dict[tuple, float] = {}
+                tenants_by_group: dict[tuple, set] = {}
+                for r in reqs:
+                    key = distance_mod.request_group_key(r, self.qb)
+                    flop_by_group[key] = flop_by_group.get(key, 0.0) + r.flop_s
+                    tenants_by_group.setdefault(key, set()).add(r.tenant)
+                dispatch_s = 0.0
+                for key, flop_s in flop_by_group.items():
+                    if rebates:
+                        flop_s = max(0.0, flop_s - rebates.get(key, 0.0))
+                    d = self.cost.fused_batch_s(flop_s, kind=key[0])
+                    initiator.t += d
+                    dispatch_s += d
+                outs = distance_mod.execute_requests(
+                    self.dist, self.qb, reqs, hbm=self.hbm, splits=splits
+                )
+                stats.score_flushes += len(flop_by_group)
+                stats.score_requests += len(reqs)
+                stats.score_rows += rows
+                n_beam = sum(
+                    1 for r in reqs if isinstance(r, beam_mod.BeamRequest)
+                )
+                stats.beam_ops += n_beam
+                stats.beam_rows += sum(
+                    r.rows for r in reqs if isinstance(r, beam_mod.BeamRequest)
+                )
+                stats.beam_flushes += sum(
+                    1 for key in flop_by_group if key[0].startswith("beam")
+                )
+                # beam replies ship a frontier, not distances — everything else
+                # in the flush still downloads its raw per-row result
+                stats.dist_downloads += len(reqs) - n_beam
+                # cross-tenant FUSION means one dispatch group genuinely spanned
+                # tenants — a flush whose per-tenant requests were routed to
+                # separate per-table calls does not count
+                if any(len(ts) > 1 for ts in tenants_by_group.values()):
+                    stats.cross_tenant_flushes += 1
+                if self.hbm is not None:
+                    n_scattered = self.hbm.scatter_staged()
+                    if n_scattered:
+                        initiator.t += max(
+                            0.0, self.cost.hbm_scatter_s - dispatch_s
+                        )
+                        if sched is not None:
+                            sched.note(("scatter", n_scattered))
+                if verify is not None:
+                    verify.at_flush()
             return outs
+
+        def execute_inline(reqs: list, **kw) -> list:
+            """The fusion-off dispatch: the request (or one shard's slice of
+            it) executes where its coroutine yielded it."""
+            rows = sum(r.rows for r in reqs)
+            with span("velo.engine.flush", requests=len(reqs), rows=rows):
+                return distance_mod.execute_requests(
+                    self.dist, self.qb, reqs, **kw
+                )
 
         def flush_scores(w: _Worker) -> None:
             """Flush the per-worker rendezvous buffer: every parked coroutine
@@ -692,11 +708,13 @@ class Engine:
                     tenants_by_group.setdefault(key, set()).add(r.tenant)
                 for key, flop_s in flop_by_group.items():
                     st += self.cost.fused_batch_s(flop_s, kind=key[0])
-                outs = distance_mod.execute_requests(self.dist, self.qb, reqs)
+                rows = sum(r.rows for r in reqs)
+                with span("velo.engine.flush", requests=len(reqs), rows=rows):
+                    outs = distance_mod.execute_requests(self.dist, self.qb, reqs)
                 router.shard_t[s] = st
                 stats.score_flushes += len(flop_by_group)
                 stats.score_requests += len(reqs)
-                stats.score_rows += sum(r.rows for r in reqs)
+                stats.score_rows += rows
                 stats.beam_flushes += sum(
                     1 for key in flop_by_group if key[0].startswith("beam")
                 )
@@ -772,7 +790,8 @@ class Engine:
 
             while True:
                 try:
-                    op = gen.send(value)
+                    with span("velo.search.step", qid=qid):
+                        op = gen.send(value)
                 except StopIteration as fin:
                     drain_pool_resumes(w.t)  # publishes from this final step
                     results[qid] = fin.value
@@ -797,7 +816,6 @@ class Engine:
                             stats.deadline_hits += 1
                         else:
                             stats.deadline_misses += 1
-                            stats.lateness_s += w.t - dl
                     if plan is not None:
                         plan.on_complete(qid, w.t, latency)
                     drop_query_tokens(qid)
@@ -839,9 +857,8 @@ class Engine:
                         ) if rebates else req.flop_s
                         d = self.cost.fused_batch_s(flop_s, kind=key[0])
                         w.t += d
-                        value = distance_mod.execute_requests(
-                            self.dist, self.qb, [req],
-                            hbm=self.hbm, splits=splits,
+                        value = execute_inline(
+                            [req], hbm=self.hbm, splits=splits
                         )[0]
                         n_scattered = self.hbm.scatter_staged()
                         if n_scattered:
@@ -850,9 +867,7 @@ class Engine:
                                 sched.note(("scatter", n_scattered))
                     else:
                         w.t += self.cost.fused_batch_s(req.flop_s)
-                        value = distance_mod.execute_requests(
-                            self.dist, self.qb, [req]
-                        )[0]
+                        value = execute_inline([req])[0]
                     stats.dist_downloads += 1
                     if verify is not None:
                         # the per-query dispatch is the degenerate flush
@@ -877,9 +892,7 @@ class Engine:
                     charge_upload(w, (req,))
                     key = distance_mod.request_group_key(req, self.qb)
                     w.t += self.cost.fused_batch_s(req.flop_s, kind=key[0])
-                    value = distance_mod.execute_requests(
-                        self.dist, self.qb, [req]
-                    )[0]
+                    value = execute_inline([req])[0]
                     stats.beam_ops += 1
                     stats.beam_flushes += 1
                     stats.beam_rows += req.rows
@@ -931,9 +944,7 @@ class Engine:
                             )
                         else:
                             st += self.cost.fused_batch_s(sub.flop_s)
-                        val = distance_mod.execute_requests(
-                            self.dist, self.qb, [sub]
-                        )[0]
+                        val = execute_inline([sub])[0]
                         router.shard_t[s] = st
                         comp = max(comp, st)
                         if join is not None:

@@ -44,6 +44,7 @@ import functools
 import numpy as np
 
 from repro.core.quant import CacheSlotView, QuantizedBase
+from repro.core.spans import span
 from repro.core.store import DecodedRecord
 from repro.velo.device_cache import (
     DeviceRecordCache,
@@ -242,33 +243,34 @@ class HbmTier:
         caller charges ``hbm_scatter_s`` net of the dispatch it overlapped."""
         if not self._staged:
             return 0
-        staged, self._staged = self._staged, []
-        self._staged_set.clear()
-        vids = np.asarray([s[0] for s in staged], dtype=np.int64)
-        exts = np.stack([s[1] for s in staged])
-        los = np.asarray([s[2] for s in staged], dtype=np.float32)
-        steps = np.asarray([s[3] for s in staged], dtype=np.float32)
-        adjs = [s[4] for s in staged]
-        self.cache.admit(
-            vids, exts, los, steps, adjs,
-            disk_pages=self.cache.disk_pages[vids],
-        )
-        installed = self.cache.record_map[vids]
-        written = installed[installed >= 0].astype(np.int64)
-        if len(written) == 0:
-            return 0
-        if self._dev is not None:
-            k = _pad_to_bucket(len(written))
-            slots = np.zeros(k, dtype=np.int64)
-            slots[: len(written)] = written
-            slots[len(written):] = written[0]  # idempotent duplicate writes
-            ext, lo, step = self._dev
-            self._dev = _scatter_fn()(
-                ext, lo, step, slots,
-                self.cache.cache_ext[slots],
-                self.cache.cache_lo[slots],
-                self.cache.cache_step[slots],
+        with span("velo.cache.hbm"):
+            staged, self._staged = self._staged, []
+            self._staged_set.clear()
+            vids = np.asarray([s[0] for s in staged], dtype=np.int64)
+            exts = np.stack([s[1] for s in staged])
+            los = np.asarray([s[2] for s in staged], dtype=np.float32)
+            steps = np.asarray([s[3] for s in staged], dtype=np.float32)
+            adjs = [s[4] for s in staged]
+            self.cache.admit(
+                vids, exts, los, steps, adjs,
+                disk_pages=self.cache.disk_pages[vids],
             )
+            installed = self.cache.record_map[vids]
+            written = installed[installed >= 0].astype(np.int64)
+            if len(written) == 0:
+                return 0
+            if self._dev is not None:
+                k = _pad_to_bucket(len(written))
+                slots = np.zeros(k, dtype=np.int64)
+                slots[: len(written)] = written
+                slots[len(written):] = written[0]  # idempotent duplicate writes
+                ext, lo, step = self._dev
+                self._dev = _scatter_fn()(
+                    ext, lo, step, slots,
+                    self.cache.cache_ext[slots],
+                    self.cache.cache_lo[slots],
+                    self.cache.cache_step[slots],
+                )
         self.scatters += 1
         return int(len(written))
 

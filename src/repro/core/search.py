@@ -56,6 +56,7 @@ from repro.core import distance as distance_mod
 from repro.core import sharding as sharding_mod
 from repro.core.quant import RabitQuantizer
 from repro.core.sim import CostModel
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -198,26 +199,35 @@ class RecordAccessor:
     def _demand_load(self, vid: int):
         """Demand-read vid's page and publish (or sync-admit) its record.
         The access was already counted/tracked by the caller."""
-        slot = self.pool.begin_load(vid) if self.async_load else -1
-        pid = self.index.page_of(vid)
+        with span("velo.cache.get"):
+            slot = self.pool.begin_load(vid) if self.async_load else -1
+            pid = self.index.page_of(vid)
         pages = yield ("read", [pid])
         self.reads += 1
         yield ("compute", self.cost.page_parse_s + self.cost.record_decode_s)
-        if slot >= 0:
-            return self._publish_from_page(vid, pages[pid])
-        # legacy path, or pool exhausted (every slot LOCKED): sync admit
-        return self._admit_from_page(vid, pages[pid])
+        with span("velo.cache.get"):
+            if slot >= 0:
+                return self._publish_from_page(vid, pages[pid])
+            # legacy path, or pool exhausted (every slot LOCKED): sync admit
+            return self._admit_from_page(vid, pages[pid])
 
-    def get(self, vid: int):
+    def _lookup(self, vid: int):
+        """The record from the HBM tier or the host pool, or None (a miss,
+        or a load in flight)."""
         self._track(vid)
         if self.hbm is not None:
             rec = self.hbm.lookup(vid)
             if rec is not None:
                 return rec  # tier hit: pool and SSD untouched
         rec = self.pool.lookup(vid)
+        if rec is not None and self.hbm is not None:
+            self.hbm.note_hit(vid, rec)  # proven hot: promote to the tier
+        return rec
+
+    def get(self, vid: int):
+        with span("velo.cache.get"):
+            rec = self._lookup(vid)
         if rec is not None:
-            if self.hbm is not None:
-                self.hbm.note_hit(vid, rec)  # proven hot: promote to the tier
             return rec
         if self.async_load:
             while self.pool.is_loading(vid):
@@ -232,28 +242,22 @@ class RecordAccessor:
         out: dict[int, object] = {}
         missing: list[int] = []
         loading: list[int] = []
-        for v in vids:
-            self._track(v)
-            if self.hbm is not None:
-                rec = self.hbm.lookup(v)
+        with span("velo.cache.get"):
+            for v in vids:
+                rec = self._lookup(v)
                 if rec is not None:
-                    out[v] = rec  # tier hit: pool and SSD untouched
-                    continue
-            rec = self.pool.lookup(v)
-            if rec is not None:
-                out[v] = rec
-                if self.hbm is not None:
-                    self.hbm.note_hit(v, rec)
-            elif self.async_load and self.pool.is_loading(v):
-                loading.append(v)
-            else:
-                missing.append(v)
+                    out[v] = rec
+                elif self.async_load and self.pool.is_loading(v):
+                    loading.append(v)
+                else:
+                    missing.append(v)
         if missing:
-            pids = sorted({self.index.page_of(v) for v in missing})
-            slots = (
-                {v: self.pool.begin_load(v) for v in missing}
-                if self.async_load else {}
-            )
+            with span("velo.cache.get"):
+                pids = sorted({self.index.page_of(v) for v in missing})
+                slots = (
+                    {v: self.pool.begin_load(v) for v in missing}
+                    if self.async_load else {}
+                )
             pages = yield ("read", pids)
             self.reads += len(pids)
             yield (
@@ -261,12 +265,13 @@ class RecordAccessor:
                 len(pids) * self.cost.page_parse_s
                 + len(missing) * self.cost.record_decode_s,
             )
-            for v in missing:
-                page = pages[self.index.page_of(v)]
-                if slots.get(v, -1) >= 0:
-                    out[v] = self._publish_from_page(v, page)
-                else:
-                    out[v] = self._admit_from_page(v, page)
+            with span("velo.cache.get"):
+                for v in missing:
+                    page = pages[self.index.page_of(v)]
+                    if slots.get(v, -1) >= 0:
+                        out[v] = self._publish_from_page(v, page)
+                    else:
+                        out[v] = self._admit_from_page(v, page)
         # park on other coroutines' in-flight loads LAST: our own loads are
         # already published, so the loaders we wait on can never be waiting
         # on us (no cross-coroutine deadlock)
@@ -296,26 +301,29 @@ class RecordAccessor:
     def prefetch_op(self, vid: int):
         """Return a fire-and-forget op loading vid's record, or None if the
         record is already present or its load is already in flight."""
-        if self.hbm is not None and self.hbm.ready(vid):
-            return None  # already served from an HBM slot: nothing to load
-        if self.pool.peek_resident(vid):
-            return None
-        pid = self.index.page_of(vid)
+        with span("velo.cache.get"):
+            if self.hbm is not None and self.hbm.ready(vid):
+                return None  # already served from an HBM slot: nothing to load
+            if self.pool.peek_resident(vid):
+                return None
+            pid = self.index.page_of(vid)
 
-        if self.async_load:
-            slot = self.pool.begin_load(vid)
-            if slot >= 0:
-                def on_publish(_pid: int, page: bytes) -> None:
-                    self._publish_from_page(vid, page)
+            if self.async_load:
+                slot = self.pool.begin_load(vid)
+                if slot >= 0:
+                    def on_publish(_pid: int, page: bytes) -> None:
+                        with span("velo.cache.get"):
+                            self._publish_from_page(vid, page)
 
-                return ("submit_cb", [pid], on_publish)
-            # every slot LOCKED: fall back to the uncached legacy prefetch
+                    return ("submit_cb", [pid], on_publish)
+                # every slot LOCKED: fall back to the uncached legacy prefetch
 
-        def on_complete(_pid: int, page: bytes) -> None:
-            if not self.pool.peek_resident(vid):
-                self._admit_from_page(vid, page)
+            def on_complete(_pid: int, page: bytes) -> None:
+                with span("velo.cache.get"):
+                    if not self.pool.peek_resident(vid):
+                        self._admit_from_page(vid, page)
 
-        return ("submit_cb", [pid], on_complete)
+            return ("submit_cb", [pid], on_complete)
 
     def stats(self) -> tuple[int, int]:
         return self.pool.hits, self.pool.misses
@@ -346,14 +354,16 @@ class PageAccessor:
         return self.cache.contains(self.index.page_of(vid))
 
     def get(self, vid: int):
-        self._track(vid)
-        pid = self.index.page_of(vid)
-        page = self.cache.lookup(pid)
+        with span("velo.cache.get"):
+            self._track(vid)
+            pid = self.index.page_of(vid)
+            page = self.cache.lookup(pid)
         if page is None:
             pages = yield ("read", [pid])
             self.reads += 1
             page = pages[pid]
-            self.cache.admit(pid, page)
+            with span("velo.cache.get"):
+                self.cache.admit(pid, page)
         yield ("compute", self.cost.page_parse_s + self.cost.record_decode_s)
         return self.index.decode_record(vid, page)
 
@@ -361,27 +371,30 @@ class PageAccessor:
         out: dict[int, object] = {}
         have: dict[int, bytes] = {}   # pid -> bytes, pinned locally for this step
         vid_page: dict[int, int] = {}
-        for v in vids:
-            self._track(v)
-            pid = self.index.page_of(v)
-            vid_page[v] = pid
-            if pid not in have:
-                page = self.cache.lookup(pid)
-                if page is not None:
-                    have[pid] = page
+        with span("velo.cache.get"):
+            for v in vids:
+                self._track(v)
+                pid = self.index.page_of(v)
+                vid_page[v] = pid
+                if pid not in have:
+                    page = self.cache.lookup(pid)
+                    if page is not None:
+                        have[pid] = page
         missing_pids = sorted({p for p in vid_page.values() if p not in have})
         if missing_pids:
             got = yield ("read", missing_pids)
             self.reads += len(missing_pids)
-            for pid, page in got.items():
-                self.cache.admit(pid, page)
-                have[pid] = page
+            with span("velo.cache.get"):
+                for pid, page in got.items():
+                    self.cache.admit(pid, page)
+                    have[pid] = page
         yield (
             "compute",
             len(vids) * (self.cost.page_parse_s + self.cost.record_decode_s),
         )
-        for v in vids:
-            out[v] = self.index.decode_record(v, have[vid_page[v]])
+        with span("velo.cache.get"):
+            for v in vids:
+                out[v] = self.index.decode_record(v, have[vid_page[v]])
         return out
 
     def install(self, vid: int, pid: int, page: bytes):

@@ -600,12 +600,10 @@ class ServingPlane:
                         ts.deadline_hits += 1
                     else:
                         ts.deadline_misses += 1
-                        ts.lateness_s += lat_by_qid[i] - win
             h1, m1 = t.accessor.stats()
             ts.cache_hits = h1 - h0
             ts.cache_misses = m1 - m0
             ts.io_count = t.accessor.reads - r0
-            ts.io_bytes = ts.io_count * self.page_size
             if hb0 is not None:
                 # per-tenant tier split from the view's own counters, as a
                 # per-run delta (same idempotence rule as cache_hits)

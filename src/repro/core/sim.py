@@ -167,14 +167,12 @@ class WorkloadStats:
     # deadline accounting (SlaPlan with deadlines; zeros otherwise)
     deadline_hits: int = 0           # completions at/before their deadline
     deadline_misses: int = 0
-    lateness_s: float = 0.0          # total seconds past deadline, misses only
     # charged coroutine switches (dispatches that paid coroutine_switch_s) —
     # the observable the rr/sla switch-accounting parity tests pin: a
     # preempted-then-resumed coroutine is charged exactly one switch under
     # either scheduler, and a flush's switch-free credit is spent exactly once
     coroutine_switches: int = 0
     io_count: int = 0
-    io_bytes: int = 0
     coalesced_reads: int = 0   # reads served by an already in-flight page (no SQE)
     cache_hits: int = 0
     cache_misses: int = 0

@@ -24,6 +24,7 @@ from repro.core import codec as codec_mod
 from repro.core import placement as placement_mod
 from repro.core.pages import PAGE_SIZE, page_lookup, page_records
 from repro.core.quant import QuantizedBase, RabitQuantizer
+from repro.core.spans import span
 from repro.core.vamana import VamanaGraph
 
 
@@ -131,10 +132,11 @@ class VeloIndex:
         return int(self.layout.colors[vid])
 
     def decode_record(self, vid: int, page: bytes) -> DecodedRecord:
-        hit = page_lookup(page, vid)
-        assert hit is not None, f"vid {vid} not on its mapped page"
-        _, payload = hit
-        return self._decode_payload(vid, payload)
+        with span("velo.cache.decode"):
+            hit = page_lookup(page, vid)
+            assert hit is not None, f"vid {vid} not on its mapped page"
+            _, payload = hit
+            return self._decode_payload(vid, payload)
 
     def _decode_payload(self, vid: int, payload: bytes) -> DecodedRecord:
         ext_len = (self.dim // 2 if self.qb.ext_bits == 4 else self.dim) + 8
@@ -153,9 +155,10 @@ class VeloIndex:
         if color == 0:
             return []
         out = []
-        for slot, payload in page_records(page):
-            if slot.color == color and slot.vid != vid:
-                out.append(self._decode_payload(slot.vid, payload))
+        with span("velo.cache.decode"):
+            for slot, payload in page_records(page):
+                if slot.color == color and slot.vid != vid:
+                    out.append(self._decode_payload(slot.vid, payload))
         return out
 
     def refine_dist2(self, pq, rec: DecodedRecord) -> float:
@@ -291,14 +294,17 @@ class FixedIndex:
         return 0
 
     def decode_record(self, vid: int, page: bytes) -> DecodedRecord:
-        slot = int(self.vid_to_slot[vid])
-        off = slot * self.record_size
-        vec = np.frombuffer(page, dtype=np.float32, count=self.dim, offset=off)
-        (deg,) = struct.unpack_from("<i", page, off + self.dim * 4)
-        adj = np.frombuffer(
-            page, dtype=np.int32, count=self.R, offset=off + self.dim * 4 + 4
-        )[:deg]
-        return DecodedRecord(vid=vid, adjacency=adj.astype(np.int64), vector=vec)
+        with span("velo.cache.decode"):
+            slot = int(self.vid_to_slot[vid])
+            off = slot * self.record_size
+            vec = np.frombuffer(page, dtype=np.float32, count=self.dim, offset=off)
+            (deg,) = struct.unpack_from("<i", page, off + self.dim * 4)
+            adj = np.frombuffer(
+                page, dtype=np.int32, count=self.R, offset=off + self.dim * 4 + 4
+            )[:deg]
+            return DecodedRecord(
+                vid=vid, adjacency=adj.astype(np.int64), vector=vec
+            )
 
     def co_resident_records(self, vid: int, page: bytes) -> list[DecodedRecord]:
         return []
