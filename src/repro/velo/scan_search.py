@@ -20,6 +20,7 @@ int4 codes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,99 @@ from repro.kernels.binary_ip.ops import binary_ip
 from repro.velo.index import DeviceIndex
 
 DEFAULT_CHUNK = 32768
+
+_POS_BITS = 16                # a packed key's low bits: the column in the row
+_POS_MASK = (1 << _POS_BITS) - 1
+_PAD_KEY = jnp.iinfo(jnp.int32).max
+
+
+def _group_size(n: int, c: int, m: int = 0) -> int | None:
+    """Group size of ``smallest``'s two-level selection of ``c`` from rows
+    of ``n`` columns after a carry of ``m``, or None where it does not apply
+    and ``lax.top_k`` selects.
+
+    The two sorts it leaves, of ceil(n/g) group minima and of c*g
+    candidates, are smallest together near g = sqrt(n/c); g is that rounded
+    to a power of two, at least 8.  None where a column does not fit the
+    key's low bits or where n/8 < c (too few groups)."""
+    if m + n >= 1 << _POS_BITS or n < 8 * c:
+        return None
+    return max(8, 1 << round(math.log2(n / c) / 2))
+
+
+def _pack(x: jnp.ndarray, col: jnp.ndarray) -> jnp.ndarray:
+    """bf16 values and their columns -> int32 keys that order as (value in
+    IEEE total order, column): the value's bits, made monotone, above the
+    column.  Unique within a row."""
+    s = jax.lax.bitcast_convert_type(x, jnp.int16).astype(jnp.int32)
+    s = s ^ ((s >> 15) & 0x7FFF)      # negatives: flip the magnitude bits
+    return (s << _POS_BITS) | col
+
+
+def _unpack(key: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Inverse of ``_pack``: (bf16 values, int32 columns), bit for bit."""
+    s = key >> _POS_BITS
+    s = s ^ ((s >> 15) & 0x7FFF)
+    x = jax.lax.bitcast_convert_type(s.astype(jnp.int16), jnp.bfloat16)
+    return x, key & _POS_MASK
+
+
+def smallest(
+    x: jnp.ndarray, c: int, carry: tuple[jnp.ndarray, jnp.ndarray] | None = None,
+    base=0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The ``c`` smallest entries of each row of ``x`` (B, n) bf16 and their
+    columns, exactly as ``v, i = lax.top_k(-x, c)`` gives ``-v, i``: ascending
+    in IEEE total order (-0 before +0), ties to the lower column.
+
+    With ``carry`` = (values (B, m) bf16, ids (B, m) int32), the entries of
+    an earlier selection placed before ``x``'s columns, it returns the c
+    smallest of both with their ids: the carry's own, or ``base`` + column.
+
+    ``lax.top_k`` over a wide row is a full two-operand (value, iota) sort of
+    the row on the TPU, not a partial selection.  Here each entry becomes one
+    unique int32 key (``_pack``), and the selection is exact in two levels:
+    the row's groups of g keys (g from ``_group_size``) give their minima;
+    the c groups with the smallest minima hold every one of the c smallest
+    keys (each such key's group has a minimum no larger than the c-th
+    smallest key, and at most c groups do); sorting those c*g keys, and the
+    carry's, gives the answer.  One-operand sorts of ~n/g and c*g keys
+    replace a two-operand sort of n; the carry's ids ride along its sort as
+    a second operand, since a gather of them costs more than the sort."""
+    if x.dtype != jnp.bfloat16:
+        raise TypeError(f"smallest packs bf16 values, not {x.dtype}")
+    b, n = x.shape
+    m = 0 if carry is None else carry[0].shape[1]
+    g = _group_size(n, c, m)
+    if g is None:
+        if carry is not None:
+            x = jnp.concatenate([carry[0], x], axis=1)
+        neg, col = jax.lax.top_k(-x, c)
+        if carry is None:
+            return -neg, col
+        kept = jnp.take_along_axis(carry[1], jnp.minimum(col, m - 1), axis=1)
+        return -neg, jnp.where(col < m, kept, base + col - m)
+    groups = -(-n // g)
+    col = jax.lax.broadcasted_iota(jnp.int32, (b, groups * g), 1) + m
+    xp = jnp.pad(x, ((0, 0), (0, groups * g - n)))
+    keys = jnp.where(col < m + n, _pack(xp, col), _PAD_KEY).reshape(b, groups, g)
+    grp = ((_lowest(keys.min(axis=2), c) & _POS_MASK) - m) // g
+    cand = jnp.take_along_axis(keys, grp[:, :, None], axis=1).reshape(b, c * g)
+    if carry is None:
+        return _unpack(_lowest(cand, c))
+    own = _pack(carry[0], jax.lax.broadcasted_iota(jnp.int32, (b, m), 1))
+    keys, ids = jax.lax.sort(
+        (jnp.concatenate([own, cand], axis=1),
+         jnp.concatenate([carry[1], base + (cand & _POS_MASK) - m], axis=1)),
+        dimension=1, is_stable=False, num_keys=1)
+    return _unpack(keys[:, :c])[0], ids[:, :c]
+
+
+def _lowest(keys: jnp.ndarray, c: int) -> jnp.ndarray:
+    """The ``c`` smallest keys of each row, ascending.  The keys are unique,
+    so the sort need not be stable, and an unstable sort is one operand on
+    the TPU (a stable one gets an iota operand to break ties)."""
+    return jax.lax.sort(keys, dimension=1, is_stable=False)[:, :c]
 
 
 @functools.partial(
@@ -86,7 +180,7 @@ def scan_search(
     if n <= chunk:
         est = stage1_block(codes, index.norms[:-1], index.ip_bar[:-1])
         with jax.named_scope("velo.scan.select"):
-            neg, cand = jax.lax.top_k(-est, C)
+            _, cand = smallest(est, C)
     else:
         nb = n // chunk
         tail = n - nb * chunk
@@ -96,23 +190,10 @@ def scan_search(
             ipb = index.ip_bar[: nb * chunk].reshape(nb, chunk)
 
         def body(carry, blk):
-            best_d, best_i = carry
             codes_blk, norms_blk, ipb_blk, bi = blk
             est = stage1_block(codes_blk, norms_blk, ipb_blk)     # (B, chunk)
-            # top-C of the CHUNK first, then a tiny 2C merge with the carry —
-            # sorting concat(C + chunk) repays the C columns every chunk and
-            # copies the concat (§Perf iteration 4).  NOTE: the residual sort
-            # volume is a CPU-lowering artifact: XLA CPU lowers top_k to a
-            # full variadic sort; the TPU backend emits a partial-reduction
-            # TopK custom call, and the production path fuses selection into
-            # the Pallas stage-1 kernel entirely (running top-C in VMEM).
             with jax.named_scope("velo.scan.select"):
-                negc, selc = jax.lax.top_k(-est, C)
-                ids = bi * chunk + selc.astype(jnp.int32)
-                all_d = jnp.concatenate([best_d, -negc], axis=1)  # (B, 2C)
-                all_i = jnp.concatenate([best_i, ids], axis=1)
-                negd, sel = jax.lax.top_k(-all_d, C)
-                return (-negd, jnp.take_along_axis(all_i, sel, axis=1)), None
+                return smallest(est, C, carry, bi * chunk), None
 
         init = (
             jnp.full((B, C), jnp.bfloat16(3e38)),
@@ -127,12 +208,7 @@ def scan_search(
                 codes[nb * chunk:], index.norms[nb * chunk : n], index.ip_bar[nb * chunk : n]
             )
             with jax.named_scope("velo.scan.select"):
-                ids = nb * chunk + jnp.arange(tail, dtype=jnp.int32)[None, :]
-                all_d = jnp.concatenate([best_d, est], axis=1)
-                all_i = jnp.concatenate(
-                    [best_i, jnp.broadcast_to(ids, est.shape)], axis=1)
-                negd, sel = jax.lax.top_k(-all_d, C)
-                best_d, best_i = -negd, jnp.take_along_axis(all_i, sel, axis=1)
+                best_d, best_i = smallest(est, C, (best_d, best_i), nb * chunk)
         cand = best_i
 
     # ---- stage 2: gather top-C, int4 refine

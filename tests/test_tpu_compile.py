@@ -119,3 +119,21 @@ def test_scan_search_compiles(one_chip):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 70e6  # the tables really are in HBM
     assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
+
+
+def test_scan_search_compiles_gist_cell(one_chip):
+    """The GIST cell's scan (250,000 x 960, 256 queries, C = 512, chunk
+    32768): its select stage runs on packed keys in groups, and the whole
+    call's temporaries fit beside the tables in HBM."""
+    specs = jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype),
+        synthetic_specs(250_000, 960, 1),
+    )
+    compiled = _compile(
+        functools.partial(scan_search, k=10, rerank=512, interpret=False,
+                          chunk=32768),
+        specs, _spec(one_chip, (256, 960), jnp.float32),
+    )
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 150e6
+    assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
