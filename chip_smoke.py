@@ -20,10 +20,11 @@ data is generated from --seed in this run; nothing is read from disk.
   (c) scan     ``velo.scan_search`` compiled over a 1M x 96 corpus whose
                level-1/level-2 tables live in HBM, 256 queries, recall@10
                against exact top-10 at SCAN_RECALL_FLOOR.
-  --chips 4    ``dist_search.make_distributed_search(mode="scan")`` over a
-               4-chip mesh, each chip holding its own shard, compared with
-               the same per-shard scans run in turn on one chip and merged by
-               ``merge_topk``: top-10 ids agree up to exact distance ties.
+  --chips 4    ``dist_search.ShardedScan`` (the program's placement and
+               entry) over a 4-chip mesh, each chip holding its own shard,
+               compared with the same per-shard scans run in turn on one chip
+               and merged by ``merge_topk``: top-10 ids agree up to exact
+               distance ties.
 
 Counts and recalls are printed; wall seconds are host-clock phase times
 (compiles included), not device throughput.  The last line of stdout is one
@@ -41,6 +42,8 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src"))
+
+from repro.velo.dist_search_ref import topk_agree  # noqa: E402
 
 DIM = 96                  # DEEP1B width (big-ann-benchmarks 2021)
 QUERIES = 256
@@ -154,57 +157,30 @@ def phase_scan(n: int, n_queries: int, seed: int) -> None:
     check(rec >= SCAN_RECALL_FLOOR, f"scan recall@10 {rec:.4f} >= {SCAN_RECALL_FLOOR}")
 
 
-def topk_agree(ids_a, d_a, ids_b, d_b, rtol=1e-5, atol=1e-5) -> bool:
-    """Row-wise equal top-k ids, except where the two differ only among
-    candidates at exactly tied distances."""
-    import numpy as np
-
-    for ia, da, ib, db in zip(ids_a, d_a, ids_b, d_b):
-        if np.array_equal(ia, ib):
-            continue
-        if not np.allclose(da, db, rtol=rtol, atol=atol):
-            return False
-        for pos in np.nonzero(ia != ib)[0]:
-            ties = np.isclose(da, da[pos], rtol=rtol, atol=atol).sum()
-            if ties < 2 and pos != len(da) - 1:
-                return False
-    return True
-
-
 def phase_sharded(n: int, n_queries: int, seed: int, devices) -> None:
-    import dataclasses
-
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.dataset import recall_at_k
     from repro.velo import dist_search
-    from repro.velo.index import DeviceIndex, from_host
+    from repro.velo.index import from_host
     from repro.velo.scan_search import scan_search
 
     S = len(devices)
     check(n % S == 0, f"corpus n={n} splits evenly over {S} chips")
-    per = n // S
     t0 = time.time()
     ds, qb = _scan_corpus(n, n_queries, seed)
-    fields = ("binary_codes", "norms", "ip_bar", "ext_codes", "ext_lo", "ext_step")
-    parts = [
-        from_host(dataclasses.replace(
-            qb, **{f: getattr(qb, f)[s * per:(s + 1) * per] for f in fields}))
-        for s in range(S)
-    ]  # one shard after another, each with its own sentinel row
-    offsets = np.arange(S, dtype=np.int32) * per
+    offsets = np.arange(S, dtype=np.int32) * (n // S)
     queries = jnp.asarray(ds.queries)
-    log(f"[4] corpus n={n} d={DIM} over {S} shards of {per} "
+    log(f"[4] corpus n={n} d={DIM} over {S} shards of {n // S} "
         f"(set-up {time.time() - t0:.1f}s wall)")
 
     # reference: each shard's scan on one chip in turn, then the same merge
     t0 = time.time()
     g_all, d_all = [], []
-    for s, part in enumerate(parts):
-        ids, d2 = scan_search(part, queries, k=K, rerank=RERANK)
+    for s, part in enumerate(dist_search.shard_rows(qb, S)):
+        ids, d2 = scan_search(from_host(part), queries, k=K, rerank=RERANK)
         g, d = dist_search.mask_local_topk(ids, d2, jnp.int32(offsets[s]))
         g_all.append(g)
         d_all.append(d)
@@ -213,25 +189,14 @@ def phase_sharded(n: int, n_queries: int, seed: int, devices) -> None:
     ref_ids, ref_d = np.asarray(ref_ids), np.asarray(ref_d)
     log(f"[4] one chip, shards in turn: wall_s={time.time() - t0:.1f}")
 
-    mesh = jax.sharding.Mesh(np.asarray(devices), ("shards",))
-    split, whole = NamedSharding(mesh, P("shards")), NamedSharding(mesh, P())
-    index = DeviceIndex(
-        centroid=jax.device_put(parts[0].centroid, whole),
-        rotation=jax.device_put(parts[0].rotation, whole),
-        medoid=jax.device_put(parts[0].medoid, whole),
-        **{f: jax.device_put(jnp.concatenate([getattr(p, f) for p in parts]), split)
-           for f in fields + ("adjacency",)},
-    )
-    shard_devs = {sh.device for sh in index.binary_codes.addressable_shards}
+    sharded = dist_search.ShardedScan(qb, devices, k=K, rerank=RERANK)
+    codes = sharded.index.binary_codes
+    shard_devs = {sh.device for sh in codes.addressable_shards}
     check(len(shard_devs) == S, f"{S} shards on {len(shard_devs)} distinct devices")
-    check(all(sh.data.shape[0] == per + 1
-              for sh in index.binary_codes.addressable_shards),
+    check(all(sh.data.shape[0] == n // S + 1 for sh in codes.addressable_shards),
           "each device holds exactly its shard")
-    search = jax.jit(dist_search.make_distributed_search(
-        mesh, ("shards",), mode="scan", L=RERANK, k=K))
     t0 = time.time()
-    ids, d2 = search(index, jax.device_put(jnp.asarray(offsets), split),
-                     jax.device_put(queries, whole))
+    ids, d2 = sharded.search(ds.queries)
     ids, d2 = np.asarray(ids), np.asarray(d2)
     log(f"[4] sharded over {S} chips: wall_s={time.time() - t0:.1f}")
 

@@ -207,15 +207,10 @@ def run_veloann_cell(multi_pod: bool) -> dict:
     offsets = jax.ShapeDtypeStruct((ndev,), jnp.int32)
     queries = jax.ShapeDtypeStruct((vcfg.query_batch, vcfg.dim), jnp.float32)
 
+    # the CPU target has no Pallas: lower each shard's stage-1 GEMM through
+    # the jnp path (interpret-mode Pallas would unroll its grid into the HLO)
     search = dist_search.make_distributed_search(
-        mesh, axes, mode=vcfg.mode, L=vcfg.rerank, k=vcfg.k, interpret=False,
-    )
-    # scan mode has no Pallas on CPU target: route through the jnp path by
-    # monkey-free flag — dist_search(mode="scan") calls binary_ip with
-    # interpret flag; interpret=False would build a TPU kernel. For the CPU
-    # dry-run we lower the jnp reference path instead:
-    search = dist_search.make_distributed_search(
-        mesh, axes, mode="scan_ref", L=vcfg.rerank, k=vcfg.k,
+        mesh, axes, mode=vcfg.mode, L=vcfg.rerank, k=vcfg.k, use_kernel=False,
     )
 
     t0 = time.time()

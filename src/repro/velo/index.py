@@ -42,10 +42,11 @@ class DeviceIndex:
         return self.adjacency.shape[1]
 
 
-def from_host(qb, graph=None) -> DeviceIndex:
-    """Build the device pytree from host-plane artifacts (QuantizedBase +
-    VamanaGraph).  Scan mode reads no adjacency: with ``graph=None`` every
-    row gets one sentinel neighbour and the medoid is row 0."""
+def host_arrays(qb, graph=None) -> dict[str, np.ndarray]:
+    """The fields of a ``DeviceIndex`` as host arrays, sentinel row appended,
+    from host-plane artifacts (QuantizedBase + VamanaGraph).  Scan mode reads
+    no adjacency: with ``graph=None`` every row gets one sentinel neighbour
+    and the medoid is row 0."""
     n = qb.norms.shape[0]
     if graph is None:
         adj, medoid = np.full((n, 1), n, dtype=np.int32), 0
@@ -54,22 +55,26 @@ def from_host(qb, graph=None) -> DeviceIndex:
         adj[adj < 0] = n  # sentinel
     sent_adj = np.full((1, adj.shape[1]), n, dtype=np.int32)
     big = np.float32(1e30)
-    return DeviceIndex(
-        centroid=jnp.asarray(qb.centroid),
-        rotation=jnp.asarray(qb.rotation),
-        binary_codes=jnp.asarray(
-            np.concatenate([qb.binary_codes, np.zeros((1, qb.binary_codes.shape[1]), np.uint8)])
-        ),
-        norms=jnp.asarray(np.concatenate([qb.norms, [big]])),
-        ip_bar=jnp.asarray(np.concatenate([qb.ip_bar, [1.0]]).astype(np.float32)),
-        ext_codes=jnp.asarray(
-            np.concatenate([qb.ext_codes, np.zeros((1, qb.ext_codes.shape[1]), np.uint8)])
-        ),
-        ext_lo=jnp.asarray(np.concatenate([qb.ext_lo, [0.0]]).astype(np.float32)),
-        ext_step=jnp.asarray(np.concatenate([qb.ext_step, [1.0]]).astype(np.float32)),
-        adjacency=jnp.asarray(np.concatenate([adj, sent_adj])),
-        medoid=jnp.asarray(medoid, dtype=jnp.int32),
+    return dict(
+        centroid=qb.centroid,
+        rotation=qb.rotation,
+        binary_codes=np.concatenate(
+            [qb.binary_codes, np.zeros((1, qb.binary_codes.shape[1]), np.uint8)]),
+        norms=np.concatenate([qb.norms, [big]]),
+        ip_bar=np.concatenate([qb.ip_bar, [1.0]]).astype(np.float32),
+        ext_codes=np.concatenate(
+            [qb.ext_codes, np.zeros((1, qb.ext_codes.shape[1]), np.uint8)]),
+        ext_lo=np.concatenate([qb.ext_lo, [0.0]]).astype(np.float32),
+        ext_step=np.concatenate([qb.ext_step, [1.0]]).astype(np.float32),
+        adjacency=np.concatenate([adj, sent_adj]),
+        medoid=np.asarray(medoid, dtype=np.int32),
     )
+
+
+def from_host(qb, graph=None) -> DeviceIndex:
+    """Build the device pytree from host-plane artifacts (``host_arrays``) on
+    the default device."""
+    return DeviceIndex(**{f: jnp.asarray(v) for f, v in host_arrays(qb, graph).items()})
 
 
 def synthetic_specs(n: int, d: int, R: int):

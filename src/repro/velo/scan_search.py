@@ -147,7 +147,12 @@ def scan_search(
     int4 gather and refine, and the final top-k)."""
     B, d = queries.shape
     with jax.named_scope("velo.scan.stage1"):
-        qr = (queries - index.centroid[None, :]) @ index.rotation.T
+        # float32, as the rerank's reference (refine_batch) rotates: at the
+        # TPU's default precision the operands are rounded to bf16, which
+        # moved the rerank's distances by up to ~6e-4 relative.  B x d x d
+        # takes microseconds even at HIGHEST's six passes.
+        qr = jnp.matmul(queries - index.centroid[None, :], index.rotation.T,
+                        precision=jax.lax.Precision.HIGHEST)
         qnorm = jnp.linalg.norm(qr, axis=1, keepdims=True)
         qunit = qr / jnp.maximum(qnorm, 1e-12)
         codes = index.binary_codes[:-1]  # drop sentinel row

@@ -12,16 +12,18 @@ workers all import this file.
 
 import functools
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import distance
 from repro.kernels.binary_ip import estimate_dist2
 from repro.kernels.binary_ip.ops import binary_ip
 from repro.kernels.int4_dist import int4_dist2
+from repro.velo import dist_search
 from repro.velo.index import synthetic_specs
 from repro.velo.scan_search import scan_search
 
@@ -31,7 +33,7 @@ N_SCALE = 1_000_000
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -45,9 +47,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -136,4 +143,28 @@ def test_scan_search_compiles_gist_cell(one_chip):
     )
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 150e6
+    assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
+
+
+def test_sharded_scan_compiles_gist1m(topo):
+    """The sharded GIST1M cell's program on the host's four chips: 4 shards
+    of 250,000 x 960, each with its sentinel row, 256 replicated queries.
+    Each chip runs the scan kernel on its shard and the merge all-gathers;
+    each holds only its shard's tables."""
+    shards, per, d = 4, 250_000, 960
+    mesh = Mesh(np.asarray(topo.devices[:shards]), (dist_search.AXIS,))
+    split, whole = NamedSharding(mesh, P(dist_search.AXIS)), NamedSharding(mesh, P())
+    specs = synthetic_specs(shards * (per + 1) - 1, d, 1)  # it adds the last sentinel
+    specs = type(specs)(**{
+        f: _spec(whole if f in dist_search.REPLICATED else split, s.shape, s.dtype)
+        for f, s in vars(specs).items()})
+    program = dist_search.sharded_scan_program(mesh, k=10, rerank=512, chunk=32768,
+                                               interpret=False)
+    compiled = program.lower(specs, _spec(split, (shards,), jnp.int32),
+                             _spec(whole, (256, d), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    assert f"jit_{dist_search.PROGRAM}" in text
+    mem = compiled.memory_analysis()           # per chip
+    assert 150e6 < mem.argument_size_in_bytes < 200e6
     assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
