@@ -131,16 +131,14 @@ def phase_scan(n: int, n_queries: int, seed: int) -> None:
 
     from repro.core.dataset import recall_at_k
     from repro.launch import compile_cache
+    from repro.velo import dist_search
     from repro.velo.index import from_host
     from repro.velo.scan_search import scan_search
 
     t0 = time.time()
     ds, qb = _scan_corpus(n, n_queries, seed)
     index = from_host(qb)
-    table_mb = sum(
-        getattr(index, f).nbytes for f in
-        ("binary_codes", "norms", "ip_bar", "ext_codes", "ext_lo", "ext_step")
-    ) / 1e6
+    table_mb = sum(getattr(index, f).nbytes for f in dist_search.ROW_FIELDS) / 1e6
     log(f"[c] corpus n={n} d={DIM}: tables {table_mb:.1f} MB on device "
         f"(set-up {time.time() - t0:.1f}s wall)")
     with compile_cache.CompileCounter() as cc:

@@ -50,8 +50,7 @@ def main():
     index = DeviceIndex(
         centroid=parts[0].centroid, rotation=parts[0].rotation,
         binary_codes=cat("binary_codes"), norms=cat("norms"),
-        ip_bar=cat("ip_bar"), ext_codes=cat("ext_codes"),
-        ext_lo=cat("ext_lo"), ext_step=cat("ext_step"),
+        ip_bar=cat("ip_bar"), ext_rows=cat("ext_rows"),
         adjacency=cat("adjacency"), medoid=parts[0].medoid,
     )
     offsets = jnp.asarray(np.arange(n_shards) * per, jnp.int32)

@@ -23,7 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.velo.index import DeviceIndex
+from repro.velo.index import DeviceIndex, refine_rows
 
 INF = jnp.float32(3e38)
 
@@ -48,18 +48,6 @@ def _estimate(index: DeviceIndex, ids: jnp.ndarray, qunit: jnp.ndarray, qnorm: j
     est_cos = jnp.clip(g / ipb, -1.0, 1.0)
     nr = index.norms[ids]
     return qnorm**2 + nr**2 - 2.0 * qnorm * nr * est_cos
-
-
-def _refine(index: DeviceIndex, ids: jnp.ndarray, qr: jnp.ndarray):
-    """Level-2 int4 refinement for gathered ids: (B, M) -> (B, M) dist^2."""
-    d = index.dim
-    packed = index.ext_codes[ids].astype(jnp.int32)      # (B, M, d/2)
-    lo4 = (packed & 0xF).astype(jnp.float32)
-    hi4 = ((packed >> 4) & 0xF).astype(jnp.float32)
-    codes = jnp.stack([lo4, hi4], axis=-1).reshape(*ids.shape, d)
-    x = codes * index.ext_step[ids][..., None] + index.ext_lo[ids][..., None]
-    diff = qr[:, None, :] - x
-    return jnp.einsum("bmd,bmd->bm", diff, diff)
 
 
 def _merge_and_trim(ids, dist, visited, new_ids, new_dist, L, sentinel):
@@ -160,7 +148,7 @@ def batch_search(
     )
 
     # final rerank: int4 refinement of the surviving beam, take top-k
-    refined = _refine(index, ids, qr)
+    refined = refine_rows(index, ids, qr)
     refined = jnp.where(dist >= INF, INF, refined)
     order = jnp.argsort(refined, axis=1)[:, :k]
     top_ids = jnp.take_along_axis(ids, order, axis=1)

@@ -32,8 +32,10 @@ PROGRAM = "sharded_scan"   # the jitted program: module events jit_sharded_scan(
 MERGE_SCOPE = "velo.shard.merge"
 AXIS = "shards"            # the sharded scan's one mesh axis
 
-# QuantizedBase fields with one entry per corpus row, split across shards
-ROW_FIELDS = ("binary_codes", "norms", "ip_bar", "ext_codes", "ext_lo", "ext_step")
+# DeviceIndex fields with one row per corpus row (and sentinel), split across shards
+ROW_FIELDS = ("binary_codes", "norms", "ip_bar", "ext_rows")
+# QuantizedBase fields with one entry per corpus row: shard_rows cuts these
+QB_ROW_FIELDS = ("binary_codes", "norms", "ip_bar", "ext_codes", "ext_lo", "ext_step")
 # DeviceIndex fields every device holds whole
 REPLICATED = ("centroid", "rotation", "medoid")
 
@@ -113,8 +115,7 @@ def make_distributed_search(
     index_specs = DeviceIndex(
         centroid=P(), rotation=P(),
         binary_codes=P(all_axes), norms=P(all_axes), ip_bar=P(all_axes),
-        ext_codes=P(all_axes), ext_lo=P(all_axes), ext_step=P(all_axes),
-        adjacency=P(all_axes), medoid=P(),
+        ext_rows=P(all_axes), adjacency=P(all_axes), medoid=P(),
     )
     in_specs = (index_specs, P(all_axes), P())
     out_specs = (P(), P())
@@ -152,7 +153,7 @@ def shard_rows(qb, shards: int) -> list:
         raise ValueError(f"corpus n={n} does not split evenly over {shards} shards")
     per = n // shards
     return [dataclasses.replace(qb, **{f: getattr(qb, f)[s * per:(s + 1) * per]
-                                       for f in ROW_FIELDS})
+                                       for f in QB_ROW_FIELDS})
             for s in range(shards)]
 
 

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.binary_ip.ops import binary_ip
-from repro.velo.index import DeviceIndex
+from repro.velo.index import DeviceIndex, refine_rows
 
 DEFAULT_CHUNK = 32768
 
@@ -216,15 +216,9 @@ def scan_search(
                 best_d, best_i = smallest(est, C, (best_d, best_i), nb * chunk)
         cand = best_i
 
-    # ---- stage 2: gather top-C, int4 refine
+    # ---- stage 2: one gather of the top-C's level-2 rows, int4 refine
     with jax.named_scope("velo.scan.rerank"):
-        packed = index.ext_codes[cand].astype(jnp.int32)    # (B, C, d/2)
-        lo4 = (packed & 0xF).astype(jnp.float32)
-        hi4 = ((packed >> 4) & 0xF).astype(jnp.float32)
-        codes4 = jnp.stack([lo4, hi4], axis=-1).reshape(B, C, d)
-        x = codes4 * index.ext_step[cand][..., None] + index.ext_lo[cand][..., None]
-        diff = qr[:, None, :] - x
-        refined = jnp.einsum("bcd,bcd->bc", diff, diff)     # (B, C)
+        refined = refine_rows(index, cand, qr)              # (B, C)
 
         kk = min(k, C)
         negk, sel = jax.lax.top_k(-refined, kk)

@@ -30,3 +30,35 @@ def small_qb(small_ds):
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(1234)
+
+
+def _int4_three_tables(qb):
+    """Level 2 as three device tables, the host ``QuantizedBase``'s
+    ``ext_codes`` / ``ext_lo`` / ``ext_step`` with the sentinel row (codes 0,
+    lo 0, step 1) appended, and the refine formula over them: the rerank's
+    arithmetic before ``DeviceIndex`` packed level 2 into ``ext_rows``."""
+    import jax.numpy as jnp
+
+    codes = jnp.asarray(np.concatenate(
+        [qb.ext_codes, np.zeros((1, qb.ext_codes.shape[1]), np.uint8)]))
+    lo = jnp.asarray(np.concatenate([qb.ext_lo, [0.0]]).astype(np.float32))
+    step = jnp.asarray(np.concatenate([qb.ext_step, [1.0]]).astype(np.float32))
+
+    def refine(ids, qr):
+        d = qr.shape[1]
+        packed = codes[ids].astype(jnp.int32)
+        lo4 = (packed & 0xF).astype(jnp.float32)
+        hi4 = ((packed >> 4) & 0xF).astype(jnp.float32)
+        codes4 = jnp.stack([lo4, hi4], axis=-1).reshape(*ids.shape, d)
+        x = codes4 * step[ids][..., None] + lo[ids][..., None]
+        diff = qr[:, None, :] - x
+        return jnp.einsum("bmd,bmd->bm", diff, diff)
+
+    return refine
+
+
+@pytest.fixture(scope="session")
+def int4_three_tables():
+    """``qb`` -> refine(ids (B, M), qr (B, d)) -> (B, M) dist^2 by the
+    three-table formula (see ``_int4_three_tables``)."""
+    return _int4_three_tables

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.core.quant import RabitQuantizer
 from repro.velo import scan_search as ss
-from repro.velo.index import DeviceIndex, from_host
+from repro.velo.index import DeviceIndex, from_host, refine_rows
 
 
 def _bits(x):
@@ -78,10 +78,12 @@ def test_smallest_matches_top_k(kind, b, n, c, m, g):
 # ------------------------------------------------------------ whole scan
 
 
-@functools.partial(jax.jit, static_argnames=("k", "rerank", "use_kernel", "chunk"))
-def scan_search_top_k(index: DeviceIndex, queries, k, rerank, use_kernel, chunk):
+@functools.partial(jax.jit, static_argnames=("refine", "k", "rerank", "use_kernel", "chunk"))
+def scan_search_top_k(index: DeviceIndex, refine, queries, k, rerank, use_kernel, chunk):
     """``scan_search`` as it was before ``smallest``: each select is a
-    ``lax.top_k`` (per chunk, then a 2C merge with the carry, then the tail)."""
+    ``lax.top_k`` (per chunk, then a 2C merge with the carry, then the tail);
+    ``refine`` is the rerank over the three level-2 tables
+    (``int4_three_tables``), as it was before ``ext_rows``."""
     from repro.kernels.binary_ip.ops import binary_ip
     from repro.kernels.binary_ip.ref import binary_ip_ref
 
@@ -139,13 +141,7 @@ def scan_search_top_k(index: DeviceIndex, queries, k, rerank, use_kernel, chunk)
             best_i = jnp.take_along_axis(all_i, sel, axis=1)
         cand = best_i
 
-    packed = index.ext_codes[cand].astype(jnp.int32)
-    lo4 = (packed & 0xF).astype(jnp.float32)
-    hi4 = ((packed >> 4) & 0xF).astype(jnp.float32)
-    codes4 = jnp.stack([lo4, hi4], axis=-1).reshape(B, C, d)
-    x = codes4 * index.ext_step[cand][..., None] + index.ext_lo[cand][..., None]
-    diff = qr[:, None, :] - x
-    refined = jnp.einsum("bcd,bcd->bc", diff, diff)
+    refined = refine(cand, qr)
     negk, sel = jax.lax.top_k(-refined, min(k, C))
     return jnp.take_along_axis(cand, sel, axis=1).astype(jnp.int32), -negk
 
@@ -157,10 +153,10 @@ def _scan_data(n: int, copies: int, d: int = 64, nq: int = 24):
     rng = np.random.default_rng(n * 10 + copies)
     base = rng.standard_normal((-(-n // copies), d)).astype(np.float32)
     base = np.tile(base, (copies, 1))[:n]
-    index = from_host(RabitQuantizer(d, seed=0).fit_encode(base))
+    qb = RabitQuantizer(d, seed=0).fit_encode(base)
     queries = base[rng.choice(n, nq, replace=False)] + 0.3 * rng.standard_normal(
         (nq, d)).astype(np.float32)
-    return index, jnp.asarray(queries)
+    return qb, from_host(qb), jnp.asarray(queries)
 
 
 SCAN_CASES = [
@@ -175,11 +171,28 @@ SCAN_CASES = [
 
 
 @pytest.mark.parametrize("n,chunk,rerank,copies,use_kernel", SCAN_CASES)
-def test_scan_search_matches_top_k_select(n, chunk, rerank, copies, use_kernel):
-    index, queries = _scan_data(n, copies)
+def test_scan_search_matches_top_k_select(n, chunk, rerank, copies, use_kernel,
+                                          int4_three_tables):
+    qb, index, queries = _scan_data(n, copies)
     kw = dict(k=10, rerank=rerank, use_kernel=use_kernel, chunk=chunk)
     ids, d2 = ss.scan_search(index, queries, **kw)
-    want_ids, want_d2 = scan_search_top_k(index, queries, **kw)
+    want_ids, want_d2 = scan_search_top_k(index, int4_three_tables(qb), queries, **kw)
     np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
     np.testing.assert_array_equal(
         np.asarray(d2).view(np.uint32), np.asarray(want_d2).view(np.uint32))
+
+
+@pytest.mark.parametrize("d", [64, 96, 960])
+def test_refine_rows_matches_three_tables(d, int4_three_tables):
+    """One gather of ``ext_rows`` refines to the three-table formula's
+    distances bit for bit, the sentinel row among the ids."""
+    rng = np.random.default_rng(d)
+    n, b, m = 300, 5, 40
+    qb = RabitQuantizer(d, seed=1).fit_encode(rng.standard_normal((n, d)).astype(np.float32))
+    ids = rng.integers(0, n + 1, (b, m)).astype(np.int32)
+    ids[:, 0] = n
+    qr = jnp.asarray(rng.standard_normal((b, d)).astype(np.float32))
+    got = jax.jit(refine_rows)(from_host(qb), jnp.asarray(ids), qr)
+    want = jax.jit(int4_three_tables(qb))(jnp.asarray(ids), qr)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))

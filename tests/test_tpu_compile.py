@@ -11,6 +11,7 @@ workers all import this file.
 """
 
 import functools
+import re
 
 import numpy as np
 import pytest
@@ -24,7 +25,7 @@ from repro.kernels.binary_ip import estimate_dist2
 from repro.kernels.binary_ip.ops import binary_ip
 from repro.kernels.int4_dist import int4_dist2
 from repro.velo import dist_search
-from repro.velo.index import synthetic_specs
+from repro.velo.index import ext_row_words, synthetic_specs
 from repro.velo.scan_search import scan_search
 
 WIDTHS = [96, 128, 960]    # DEEP, SIFT, GIST
@@ -128,22 +129,42 @@ def test_scan_search_compiles(one_chip):
     assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
 
 
-def test_scan_search_compiles_gist_cell(one_chip):
+@pytest.fixture(scope="module")
+def gist_cell_scan(one_chip):
     """The GIST cell's scan (250,000 x 960, 256 queries, C = 512, chunk
-    32768): its select stage runs on packed keys in groups, and the whole
-    call's temporaries fit beside the tables in HBM."""
+    32768), compiled for the described chip."""
     specs = jax.tree.map(
         lambda s: _spec(one_chip, s.shape, s.dtype),
         synthetic_specs(250_000, 960, 1),
     )
-    compiled = _compile(
+    return _compile(
         functools.partial(scan_search, k=10, rerank=512, interpret=False,
                           chunk=32768),
         specs, _spec(one_chip, (256, 960), jnp.float32),
     )
-    mem = compiled.memory_analysis()
+
+
+def test_scan_search_compiles_gist_cell(gist_cell_scan):
+    """The GIST cell's scan: its select stage runs on packed keys in groups,
+    and the whole call's temporaries fit beside the tables in HBM."""
+    mem = gist_cell_scan.memory_analysis()
     assert mem.argument_size_in_bytes > 150e6
     assert mem.temp_size_in_bytes < 16 * 2**30 - mem.argument_size_in_bytes
+
+
+def test_scan_rerank_reads_level2_in_place(gist_cell_scan):
+    """The GIST cell's rerank reads the level-2 table where it lies: one
+    instruction reads the ``ext_rows`` parameter (the candidates' row
+    gather), and no instruction copies a whole (n+1)-row table."""
+    text = gist_cell_scan.as_text()
+    entry = text[text.index("\nENTRY"):]
+    table = rf"u32\[250001,{ext_row_words(960)}\]"
+    params = re.findall(rf"(%[\w.\-]+) = {table}\S* parameter\(", entry)
+    assert len(params) == 1
+    readers = [ln for ln in entry.splitlines()[1:]
+               if params[0] + "," in ln or params[0] + ")" in ln]
+    assert len(readers) == 1 and " copy" not in readers[0]
+    assert not re.search(r"= \w+\[250001,\d+\]\S* copy(-start)?\(", text)
 
 
 def test_sharded_scan_compiles_gist1m(topo):

@@ -16,7 +16,8 @@ from repro.velo.device_cache import (
     MARKED,
     OCCUPIED,
 )
-from repro.velo.index import from_host
+from repro.core.quant import RabitQuantizer, unpack_nibbles
+from repro.velo.index import ext_row_words, from_host, host_arrays, unpack_ext_rows
 
 
 @pytest.fixture(scope="module")
@@ -64,8 +65,6 @@ def test_scan_beats_graph_recall(small_ds, dev_index):
 
 def test_device_matches_host_distance_semantics(small_ds, small_qb, small_graph, dev_index):
     """Refined distances from the device search equal the host quantizer's."""
-    from repro.core.quant import RabitQuantizer
-
     q = jnp.asarray(small_ds.queries[:4])
     ids, d2, _ = bs.batch_search(dev_index, q, L=32, k=5, max_steps=64)
     ids, d2 = np.asarray(ids), np.asarray(d2)
@@ -73,6 +72,59 @@ def test_device_matches_host_distance_semantics(small_ds, small_qb, small_graph,
         pq = RabitQuantizer.prepare_query(small_qb, small_ds.queries[i])
         host = RabitQuantizer.refine_dist2(small_qb, pq, ids[i])
         np.testing.assert_allclose(d2[i], host, rtol=2e-3, atol=2e-3)
+
+
+def test_batch_search_refine_matches_three_tables(small_ds, small_qb, dev_index,
+                                                  int4_three_tables):
+    """batch_search's distances are the three-table int4 formula's, bit for
+    bit, for the ids it returns."""
+    q = jnp.asarray(small_ds.queries[:6])
+    ids, d2, _ = bs.batch_search(dev_index, q, L=32, k=5, max_steps=64)
+    qr = jax.jit(bs._prepare_queries)(dev_index, q)[0]
+    want = jax.jit(int4_three_tables(small_qb))(ids, qr)
+    np.testing.assert_array_equal(np.asarray(d2).view(np.uint32),
+                                  np.asarray(want).view(np.uint32))
+
+
+# ------------------------------------------------------------- level-2 rows
+
+
+@pytest.mark.parametrize("d,words", [(64, 16), (96, 16), (960, 128)])
+def test_ext_rows_round_trip(d, words):
+    """``ext_rows`` holds each row's nibble codes, lo and step, and the
+    sentinel's (codes 0, lo 0.0, step 1.0): unpacked on the device they
+    equal the host QuantizedBase's, bit for bit; the rest is zeros."""
+    rng = np.random.default_rng(d)
+    n = 37
+    qb = RabitQuantizer(d, seed=1).fit_encode(rng.standard_normal((n, d)).astype(np.float32))
+    rows = host_arrays(qb)["ext_rows"]
+    assert rows.dtype == np.uint32 and rows.shape == (n + 1, words)
+    codes, lo, step = jax.jit(unpack_ext_rows, static_argnums=1)(jnp.asarray(rows), d)
+    want_codes = np.concatenate([unpack_nibbles(qb.ext_codes, d), np.zeros((1, d), np.uint8)])
+    np.testing.assert_array_equal(np.asarray(codes), want_codes.astype(np.float32))
+    for got, want in ((lo, [*qb.ext_lo, 0.0]), (step, [*qb.ext_step, 1.0])):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32),
+                                      np.asarray(want, np.float32).view(np.uint32))
+    assert not rows[:, d // 8 + 2:].any()
+
+
+@pytest.mark.parametrize("d,words", [
+    (8, 8), (64, 16), (96, 16), (128, 24), (496, 64),   # up to 64 words: 8s
+    (504, 128), (960, 128), (1024, 256),                 # beyond: 128-lane rows
+])
+def test_ext_row_words_padding_rule(d, words):
+    assert ext_row_words(d) == words
+    assert words >= d // 8 + 2
+
+
+def test_ext_rows_refuse_widths_and_8bit_codes():
+    with pytest.raises(ValueError):
+        ext_row_words(100)
+    rng = np.random.default_rng(0)
+    qb8 = RabitQuantizer(64, seed=0, ext_bits=8).fit_encode(
+        rng.standard_normal((20, 64)).astype(np.float32))
+    with pytest.raises(ValueError):
+        host_arrays(qb8)
 
 
 # ------------------------------------------------------------- device cache
