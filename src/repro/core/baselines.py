@@ -44,130 +44,6 @@ from repro.core.store import FixedIndex, VeloIndex
 from repro.core.vamana import VamanaGraph
 
 
-_DEFAULT_FUSE = False
-_DEFAULT_FUSE_ROWS = 256
-_DEFAULT_SHARED_RV = False
-_DEFAULT_OVERLAP = False
-_DEFAULT_CALIBRATION: dict | None = None
-_DEFAULT_HBM = False
-_DEFAULT_HBM_SLOTS: int | None = None
-_DEFAULT_DEVICE_BEAM = False
-_DEFAULT_SCHEDULER = "rr"
-_DEFAULT_SLA_MS: float | list | None = None
-
-
-def set_default_fuse(
-    on: bool, rows: int | None = None, shared: bool | None = None,
-    overlap: bool | None = None,
-) -> None:
-    """Process-wide default for cross-query fused score dispatch — the hook
-    ``benchmarks/run.py --fuse`` threads through (mirrors
-    ``distance.set_default_backend``).  ``shared`` flips the rendezvous
-    topology every system inherits (one system-wide buffer vs per-worker);
-    ``overlap`` lets the shared-rendezvous stall flush overlap another
-    worker's in-flight completions instead of draining them first."""
-    global _DEFAULT_FUSE, _DEFAULT_FUSE_ROWS, _DEFAULT_SHARED_RV, _DEFAULT_OVERLAP
-    _DEFAULT_FUSE = bool(on)
-    if rows is not None:
-        _DEFAULT_FUSE_ROWS = int(rows)
-    if shared is not None:
-        _DEFAULT_SHARED_RV = bool(shared)
-    if overlap is not None:
-        _DEFAULT_OVERLAP = bool(overlap)
-
-
-def default_fuse() -> tuple[bool, int]:
-    return _DEFAULT_FUSE, _DEFAULT_FUSE_ROWS
-
-
-def default_shared_rendezvous() -> bool:
-    return _DEFAULT_SHARED_RV
-
-
-def default_overlap_flush() -> bool:
-    return _DEFAULT_OVERLAP
-
-
-def set_default_hbm(on: bool, slots: int | None = None) -> None:
-    """Process-wide default for the HBM record-cache tier — the hook
-    ``benchmarks/run.py --hbm-tier`` threads through.  ``slots`` fixes the
-    device slot count (None: match the host pool's slot count)."""
-    global _DEFAULT_HBM, _DEFAULT_HBM_SLOTS
-    _DEFAULT_HBM = bool(on)
-    if slots is not None:
-        _DEFAULT_HBM_SLOTS = int(slots)
-
-
-def default_hbm() -> tuple[bool, int | None]:
-    return _DEFAULT_HBM, _DEFAULT_HBM_SLOTS
-
-
-def set_default_device_beam(on: bool) -> None:
-    """Process-wide default for the fused on-device beam step — the hook
-    ``benchmarks/run.py --device-beam`` threads through.  When on, search
-    coroutines keep their beam state engine-resident and yield one
-    ``("beam", ...)`` op per hop instead of downloading raw distances
-    (core.beam, docs/beam_step.md)."""
-    global _DEFAULT_DEVICE_BEAM
-    _DEFAULT_DEVICE_BEAM = bool(on)
-
-
-def default_device_beam() -> bool:
-    return _DEFAULT_DEVICE_BEAM
-
-
-def set_default_scheduler(
-    scheduler: str, sla_ms: float | list | None = None
-) -> None:
-    """Process-wide default for the coroutine scheduling policy — the hook
-    ``benchmarks/run.py --scheduler/--sla-ms`` threads through.  "rr" is
-    FIFO round-robin (bitwise the pre-SLA engine); "sla" is EDF ordering by
-    the per-tenant deadlines ``sla_ms`` induces (docs/scheduling.md)."""
-    global _DEFAULT_SCHEDULER, _DEFAULT_SLA_MS
-    from repro.core.scheduling import SCHEDULERS
-
-    assert scheduler in SCHEDULERS, f"unknown scheduler {scheduler!r}"
-    _DEFAULT_SCHEDULER = scheduler
-    if sla_ms is not None:
-        _DEFAULT_SLA_MS = sla_ms
-
-
-def default_scheduler() -> tuple[str, float | list | None]:
-    return _DEFAULT_SCHEDULER, _DEFAULT_SLA_MS
-
-
-def set_default_calibration(calib: dict | None) -> None:
-    """Process-wide per-backend CostModel overrides, as emitted by
-    ``benchmarks/calibrate.py`` ({backend: {cost_field: seconds}}).  Systems
-    built with ``SystemConfig.calibration=None`` inherit it."""
-    global _DEFAULT_CALIBRATION
-    _DEFAULT_CALIBRATION = calib
-
-
-def load_calibration(source) -> dict | None:
-    """Normalize a calibration source: a dict passes through, a str/Path is
-    read as the JSON file calibrate.py writes, None returns None."""
-    if source is None or isinstance(source, dict):
-        return source
-    import json
-
-    with open(source) as f:
-        return json.load(f)
-
-
-def apply_calibration(cost: CostModel, backend: str, calib: dict | None) -> CostModel:
-    """A CostModel with ``calib[backend]``'s measured per-backend constants
-    (dispatch / table-upload seconds) replacing the defaults.  Unknown keys
-    are ignored so calibration files can carry extra diagnostics."""
-    overrides = (calib or {}).get(backend)
-    if not overrides:
-        return cost
-    fields = {f.name for f in dataclasses.fields(CostModel)}
-    return dataclasses.replace(
-        cost, **{k: float(v) for k, v in overrides.items() if k in fields}
-    )
-
-
 @dataclasses.dataclass
 class SystemConfig:
     name: str = "velo"
@@ -185,37 +61,32 @@ class SystemConfig:
     group_demote: bool = False    # clock demotes co-admitted groups together
     track_access: bool = False    # per-vertex/page counters (Fig. 4)
     seed: int = 0
-    distance_backend: str = "default"  # scalar | batch | pallas | default
-    fuse: bool | None = None      # cross-query fused dispatch (None -> process default)
-    fuse_rows: int | None = None  # rendezvous flush row budget (None -> default)
-    shared_rendezvous: bool | None = None  # one system-wide rendezvous buffer
-                                  # spanning all workers (None -> process
-                                  # default; off = per-worker PR-2 semantics)
-    overlap_flush: bool | None = None  # overlap the shared-rendezvous stall
+    distance_backend: str = "batch"  # scalar | batch | pallas
+    fuse: bool = False            # cross-query fused dispatch
+    fuse_rows: int = 256          # rendezvous flush row budget
+    shared_rendezvous: bool = False  # one system-wide rendezvous buffer
+                                  # spanning all workers (off = per-worker
+                                  # PR-2 semantics)
+    overlap_flush: bool = False   # overlap the shared-rendezvous stall
                                   # flush with other workers' in-flight
-                                  # completions (None -> process default)
+                                  # completions
     tenant_quota: float | None = None  # serving plane: per-tenant soft cap on
                                   # shared-pool slots, as a fraction of the
                                   # pool (None/0 = pure global clock)
     resident_plane: bool = True   # register-once resident tables + id-based
                                   # refine requests (False = host-gather PR-2
                                   # semantics: per-call row materialization)
-    calibration: dict | str | None = None  # per-backend CostModel overrides
-                                  # ({backend: {field: s}} or a path to
-                                  # calibrate.py's JSON; None -> process default)
-    hbm_tier: bool | None = None  # device-resident record-cache tier above
-                                  # the host pool (None -> process default;
-                                  # only record-pool systems build one)
-    hbm_slots: int | None = None  # HBM tier slot count (None -> process
-                                  # default, which falls back to the host
+    hbm_tier: bool = False        # device-resident record-cache tier above
+                                  # the host pool (only record-pool systems
+                                  # build one)
+    hbm_slots: int | None = None  # HBM tier slot count (None = the host
                                   # pool's slot count)
-    device_beam: bool | None = None  # fused on-device beam step: one
+    device_beam: bool = False     # fused on-device beam step: one
                                   # ("beam", ...) op per hop — score +
                                   # visited mask + top-k merge + frontier
                                   # selection in a single engine call, reply
-                                  # is the FRONTIER (None -> process
-                                  # default; off = the host-beam bitwise
-                                  # reference path)
+                                  # is the FRONTIER (off = the host-beam
+                                  # bitwise reference path)
     n_shards: int | None = None   # sharded scatter-gather serving plane
                                   # (core.sharding): split the index image
                                   # across this many engine shards, each with
@@ -223,14 +94,13 @@ class SystemConfig:
                                   # clock; score work scatters to the owning
                                   # shards and merges per flush.  None/0 =
                                   # unsharded.  n_shards=1 is bitwise
-                                  # identical to unsharded (the parity
-                                  # contract bench_sharded.py enforces).
-    scheduler: str | None = None  # coroutine scheduling policy: "rr" = FIFO
+                                  # identical to unsharded
+                                  # (tests/test_sharding.py).
+    scheduler: str = "rr"         # coroutine scheduling policy: "rr" = FIFO
                                   # round-robin, bitwise the pre-SLA engine;
                                   # "sla" = EDF by deadline slack (admission,
                                   # ready picks, stall-flush initiator), fed
-                                  # by sla_ms deadlines (None -> process
-                                  # default; see docs/scheduling.md)
+                                  # by sla_ms deadlines (docs/scheduling.md)
     sla_ms: float | list | None = None  # per-tenant latency target in ms
                                   # (scalar = every tenant; sequence = one
                                   # per tenant).  Induces per-query deadlines
@@ -262,12 +132,14 @@ class System:
     algorithm: object
     store: object
     cost: CostModel
+    batch_size: int  # B this system runs (1 for the synchronous baselines)
+    params: SearchParams  # config.params, with a breakdown variant's switches
     hbm: object | None = None  # HbmTier when the device record tier is on
     checker: object | None = None  # ProtocolChecker when verify_protocol is on
     shard_plan: object | None = None  # sharding.ShardPlan when n_shards is set
 
     def make_coroutine(self, qid: int, q: np.ndarray):
-        return self.algorithm(self.ctx, q, self.config.params)
+        return self.algorithm(self.ctx, q, self.params)
 
     def run(
         self, queries: np.ndarray, ssd_config: SSDConfig | None = None,
@@ -296,15 +168,15 @@ class System:
             cost=self.cost,
             ssd=ssd,
             n_workers=self.config.n_workers,
-            batch_size=self.config.batch_size,
+            batch_size=self.batch_size,
             page_size=self.config.page_size,
             dist=self.ctx.dist,
             qb=self.ctx.qb,
             fuse=self.config.fuse,
             fuse_rows=self.config.fuse_rows,
-            shared_rendezvous=bool(self.config.shared_rendezvous),
-            overlap_flush=bool(self.config.overlap_flush),
-            scheduler=self.config.scheduler or "rr",
+            shared_rendezvous=self.config.shared_rendezvous,
+            overlap_flush=self.config.overlap_flush,
+            scheduler=self.config.scheduler,
             hbm=self.hbm,
             schedule=schedule,
             verify=self.checker,
@@ -365,52 +237,13 @@ def build_system(
     config: SystemConfig | None = None,
     cost: CostModel | None = None,
 ) -> System:
-    config = config or SystemConfig()
-    fuse_on, fuse_rows = default_fuse()
-    config = dataclasses.replace(
-        config,
-        name=name,
-        fuse=fuse_on if config.fuse is None else config.fuse,
-        fuse_rows=fuse_rows if config.fuse_rows is None else config.fuse_rows,
-        shared_rendezvous=(
-            default_shared_rendezvous()
-            if config.shared_rendezvous is None else config.shared_rendezvous
-        ),
-        overlap_flush=(
-            default_overlap_flush()
-            if config.overlap_flush is None else config.overlap_flush
-        ),
-        hbm_tier=(
-            default_hbm()[0] if config.hbm_tier is None else config.hbm_tier
-        ),
-        hbm_slots=(
-            default_hbm()[1] if config.hbm_slots is None else config.hbm_slots
-        ),
-        device_beam=(
-            default_device_beam()
-            if config.device_beam is None else config.device_beam
-        ),
-        scheduler=(
-            default_scheduler()[0]
-            if config.scheduler is None else config.scheduler
-        ),
-        sla_ms=(
-            default_scheduler()[1]
-            if config.sla_ms is None else config.sla_ms
-        ),
-    )
+    config = dataclasses.replace(config or SystemConfig(), name=name)
     cost = cost or CostModel()
-    # ONE engine per system (its name resolves "default" for the
-    # calibration lookup)
+    params = config.params
+    # ONE engine per system
     dist_engine = distance_mod.get_engine(
         config.distance_backend, resident=config.resident_plane
     )
-    calib = load_calibration(
-        config.calibration if config.calibration is not None
-        else _DEFAULT_CALIBRATION
-    )
-    if calib:
-        cost = apply_calibration(cost, dist_engine.name, calib)
     n, dim = base.shape
 
     def record_pool_for(index) -> RecordAccessor:
@@ -489,16 +322,12 @@ def build_system(
         algo = search_mod.ALGORITHMS[spec["algo"]]
         refine = cost.refine_ext(dim)
         batch = spec["batch"] or config.batch_size
-        config = dataclasses.replace(
-            config,
-            params=dataclasses.replace(
-                config.params, prefetch=spec["prefetch"], cbs=spec["cbs"]
-            ),
+        params = dataclasses.replace(
+            config.params, prefetch=spec["prefetch"], cbs=spec["cbs"]
         )
     else:
         raise ValueError(f"unknown system {name!r}")
 
-    config = dataclasses.replace(config, batch_size=batch)
     shard_plan = None
     if config.n_shards:
         # the sharded scatter-gather plane: page->shard ownership derived
@@ -550,7 +379,7 @@ def build_system(
         dist=dist_engine,
         resident_ids=config.resident_plane,
         shard_plan=shard_plan,
-        device_beam=bool(config.device_beam),
+        device_beam=config.device_beam,
     )
     return System(
         name=name,
@@ -560,6 +389,8 @@ def build_system(
         algorithm=algo,
         store=index.store,
         cost=cost,
+        batch_size=batch,
+        params=params,
         hbm=hbm,
         checker=checker,
         shard_plan=shard_plan,
@@ -594,11 +425,11 @@ def evaluate(
     return {
         "system": system.name,
         "distance_backend": system.ctx.dist.name,
-        "fuse": bool(system.config.fuse),
-        "shared_rendezvous": bool(system.config.shared_rendezvous),
-        "overlap_flush": bool(system.config.overlap_flush),
-        "resident_plane": bool(system.config.resident_plane),
-        "scheduler": system.config.scheduler or "rr",
+        "fuse": system.config.fuse,
+        "shared_rendezvous": system.config.shared_rendezvous,
+        "overlap_flush": system.config.overlap_flush,
+        "resident_plane": system.config.resident_plane,
+        "scheduler": system.config.scheduler,
         "recall@k": rec,
         "qps": stats.qps,
         "mean_latency_ms": stats.mean_latency_ms,
@@ -626,7 +457,7 @@ def evaluate(
         "scatter_ops": stats.scatter_ops,
         "shard_flushes": stats.shard_flushes,
         "shard_merges": stats.shard_merges,
-        "device_beam": bool(system.config.device_beam),
+        "device_beam": system.config.device_beam,
         "beam_ops": stats.beam_ops,
         "beam_flushes": stats.beam_flushes,
         "beam_rows": stats.beam_rows,

@@ -13,11 +13,10 @@ distance evaluated by the search plane goes through one of these engines:
 
 Selection:
 
-  get_engine("scalar" | "batch" | "pallas" | "default" | None)
+  get_engine("scalar" | "batch" | "pallas")
 
-``default`` (and None) resolve to the process-wide default set with
-``set_default_backend`` — the hook benchmarks/run.py's ``--backend`` flag
-threads through without touching every call site.
+The caller always names the engine; ``SystemConfig.distance_backend``
+defaults to ``batch``.
 
 Resident code plane (register-once tables):
 
@@ -29,7 +28,7 @@ arrays via ``jax.device_put`` for the Pallas backend (the
 the registered table: on-device inside the jitted kernel wrappers for
 ``pallas``, one fancy-index per table for the host backends.  Registration is
 lazy (first id-based call registers) and idempotent; ``DistanceStats.uploads``
-counts table uploads so benchmarks can assert they are O(1) per index rather
+counts table uploads so tests can assert they are O(1) per index rather
 than O(hops).  The matrix-consuming entry points (``refine`` over payload
 rows, the ``*_many`` matrix hooks) remain for the host-gather parity path and
 for ext_bits=8 records — on the Pallas backend each such call re-uploads its
@@ -59,26 +58,6 @@ from repro.core.quant import (
 )
 
 BACKENDS = ("scalar", "batch", "pallas")
-
-_DEFAULT_BACKEND = "batch"
-
-
-def set_default_backend(name: str) -> None:
-    """Set the process-wide default backend (see ``get_engine``)."""
-    global _DEFAULT_BACKEND
-    if name not in BACKENDS:
-        raise ValueError(f"unknown distance backend {name!r}; expected {BACKENDS}")
-    _DEFAULT_BACKEND = name
-
-
-def default_backend() -> str:
-    return _DEFAULT_BACKEND
-
-
-def resolved_backend(name: str | None = None) -> str:
-    """The engine name ``get_engine(name)`` would serve (resolves
-    ``default``)."""
-    return get_engine(name).name
 
 
 @dataclasses.dataclass
@@ -780,7 +759,7 @@ class BatchEngine(DistanceEngine):
 
 # Jitted device-gather wrappers for the resident pallas path, built once per
 # process (NOT per engine instance — a per-instance closure would defeat the
-# jit cache and recompile for every system the benchmarks build).
+# jit cache and recompile for every system a process builds).
 _PALLAS_RESIDENT_FNS = None
 
 
@@ -1254,12 +1233,10 @@ class PallasEngine(BatchEngine):
         return out[owner, np.arange(m)].astype(np.float32, copy=False)
 
 
-def get_engine(name: str | None = None, resident: bool = True) -> DistanceEngine:
-    """Build a fresh engine for ``name`` (see module docstring for the rules).
+def get_engine(name: str, resident: bool = True) -> DistanceEngine:
+    """Build a fresh engine for ``name``, one of ``BACKENDS``.
     ``resident=False`` keeps the PR-2 host-gather semantics on the pallas
     path (per-call row uploads) — the parity/ablation baseline."""
-    if name is None or name == "default":
-        name = _DEFAULT_BACKEND
     if name == "scalar":
         return ScalarEngine(resident=resident)
     if name == "batch":

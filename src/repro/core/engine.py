@@ -88,7 +88,7 @@ reads route to the owning shard's SSD.  A scatter whose rows all land on one
 shard passes the ORIGINAL request through — with one shard every scatter
 does, every flush charge lands at the same time on the same clock, and the
 sharded engine is bitwise identical to the unsharded one (the S=1 parity
-contract; tests/test_sharding.py, benchmarks/bench_sharded.py).  Resident
+contract; tests/test_sharding.py).  Resident
 code tables upload once per (shard, table): each shard pins its own copy.
 
 Fused on-device beam steps (``SearchContext.device_beam``, core.beam):
@@ -229,7 +229,7 @@ class Engine:
         cfg = self.config
         assert cfg.scheduler in SCHEDULERS, f"unknown scheduler {cfg.scheduler!r}"
         if self.dist is None:
-            self.dist = distance_mod.get_engine()
+            self.dist = distance_mod.get_engine("batch")
         # schedule-exploration / protocol-verification seams (both None in
         # production: the identity schedule and no checker are bitwise the
         # pre-seam engine — tests/test_analysis.py pins that parity)

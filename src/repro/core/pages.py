@@ -11,8 +11,8 @@ Layout of one PAGE_SIZE-byte page:
   heap   : record payloads, growing backward from the page end
 
 "Two-way growth design achieves dense packing to fully utilize available page
-space."  PageBuilder enforces exactly that invariant; fragmentation accounting
-feeds benchmarks/bench_fragmentation.py (Fig. 6).
+space."  PageBuilder enforces exactly that invariant; tests/test_pages.py
+holds the fragmentation accounting to Fig. 6.
 """
 
 from __future__ import annotations

@@ -47,7 +47,7 @@ Windows are multisets pruned by time, decisions are computed from sorted
 window content, so any permutation of equal-time updates lands in the same
 state.  (The controller is still input-adaptive with respect to timing *by
 design* — like velo's cache-aware pivot, exploration covers the pure-EDF
-scheduler and the feedback loop is exercised by the benchmarks; see
+scheduler and tests/test_scheduling.py exercises the feedback loop; see
 docs/scheduling.md.)
 """
 

@@ -81,7 +81,7 @@ class SearchContext:
     # CPU charge for one record refinement: 4-bit dequant distance on the
     # compressed index, full fp32 distance on the DiskANN-style index.
     refine_cost_s: float = 0.0
-    dist: object | None = None      # DistanceEngine; None -> process default
+    dist: object | None = None      # DistanceEngine; None -> a batch engine
     # resident wire format: refine ScoreRequests carry vertex ids, resolved
     # against the engine's registered tables (False = PR-2 semantics, the
     # coroutine materializes code matrices from the fetched payload bytes)
@@ -109,7 +109,7 @@ class SearchContext:
 
     def __post_init__(self):
         if self.dist is None:
-            self.dist = distance_mod.get_engine()
+            self.dist = distance_mod.get_engine("batch")
         if self.table_qb is None:
             self.table_qb = self.qb
 

@@ -34,8 +34,8 @@ one ``WorkloadStats`` + recall per tenant — the serving-side axes (recall /
 QPS / p99 / hit rate) sliced the way an operator would dashboard them.
 
 Workloads come from ``repro.core.workload`` (uniform / zipfian hot-tenant /
-bursty arrival mixes); ``benchmarks/bench_multitenant.py`` compares the
-shared pool against the static partition under skew.
+bursty arrival mixes); ``tests/test_serving.py`` compares the shared pool
+against the static partition under skew.
 """
 
 from __future__ import annotations
@@ -282,7 +282,7 @@ class ServingPlane:
         self.config = config or baselines_mod.SystemConfig()
         self.shared_pool_mode = shared_pool
 
-        # ---- per-tenant builds (index image, algorithm, resolved config) --
+        # ---- per-tenant builds (index image, algorithm, config) -----------
         built = []
         for spec in specs:
             cfg_t = dataclasses.replace(
@@ -345,16 +345,9 @@ class ServingPlane:
         # the combined table (required: slot gathers index the one registered
         # table).  Static-partition mode gets no tier — it is the baseline.
         self.hbm: HbmTier | None = None
-        hbm_on = (
-            baselines_mod.default_hbm()[0]
-            if self.config.hbm_tier is None else self.config.hbm_tier
-        )
-        if hbm_on and self.pool is not None and self.table is not None:
-            slots = (
-                self.config.hbm_slots
-                or baselines_mod.default_hbm()[1]
-                or self.pool.n_slots
-            )
+        if (self.config.hbm_tier and self.pool is not None
+                and self.table is not None):
+            slots = self.config.hbm_slots or self.pool.n_slots
             max_r = max(int(s.graph.R) for s in specs)
             self.hbm = HbmTier(
                 self.table, global_vtp,
@@ -409,7 +402,7 @@ class ServingPlane:
             )
             self.tenants.append(Tenant(
                 tid=i, spec=spec, system=b, ctx=ctx, accessor=acc,
-                algorithm=b.algorithm, params=b.config.params,
+                algorithm=b.algorithm, params=b.params,
                 vid_base=vid_bases[i], page_base=page_bases[i],
             ))
 
@@ -435,22 +428,17 @@ class ServingPlane:
 
         # sync tenants (diskann/starling/pipeann are B=1 systems) clamp the
         # shared engine's per-worker batch: one scheduler serves everyone
-        self.batch_size = min(b.config.batch_size for b in built)
-        cfg0 = built[0].config
+        self.batch_size = min(b.batch_size for b in built)
+        cfg = self.config
         self.engine_config = EngineConfig(
-            n_workers=self.config.n_workers,
+            n_workers=cfg.n_workers,
             batch_size=self.batch_size,
             page_size=self.page_size,
-            fuse=bool(cfg0.fuse),
-            fuse_rows=cfg0.fuse_rows,
-            shared_rendezvous=bool(cfg0.shared_rendezvous),
-            overlap_flush=bool(cfg0.overlap_flush),
-            scheduler=cfg0.scheduler,
-        )
-        # resolve the None->process-default fields run() reads off the
-        # plane's own config (build_system resolved them on each tenant)
-        self.config = dataclasses.replace(
-            self.config, scheduler=cfg0.scheduler, sla_ms=cfg0.sla_ms,
+            fuse=cfg.fuse,
+            fuse_rows=cfg.fuse_rows,
+            shared_rendezvous=cfg.shared_rendezvous,
+            overlap_flush=cfg.overlap_flush,
+            scheduler=cfg.scheduler,
         )
         self.cost = built[0].cost
 

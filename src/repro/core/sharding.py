@@ -26,10 +26,10 @@ frontier to the owning shards:
     the same masking discipline: a shard only ever contributes the rows it
     owns, so no sentinel row can win the merge).
 
-The contract that keeps the plane honest (tests/test_sharding.py,
-benchmarks/bench_sharded.py): with one shard the sharded engine is BITWISE
-identical to the unsharded engine for all five algorithms, and QPS scales
-near-linearly in shards at flat recall.  See docs/sharding.md.
+The contract that keeps the plane honest (tests/test_sharding.py): with one
+shard the sharded engine is BITWISE identical to the unsharded engine for all
+five algorithms, and recall stays flat across shard counts.  See
+docs/sharding.md.
 """
 
 from __future__ import annotations

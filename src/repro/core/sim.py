@@ -75,10 +75,9 @@ class CostModel:
                                          # at PCIe rates), charged per
                                          # registered index, NOT per hop
     full_dispatch_s: float = 0.3e-6      # dispatch of an fp32 refine_full batch
-                                         # (BLAS GEMV path) — calibrated apart
+                                         # (BLAS GEMV path), a constant apart
                                          # from the int4 refine dispatch; the
-                                         # default equals batch_dispatch_s so
-                                         # uncalibrated runs are unchanged
+                                         # default equals batch_dispatch_s
     hbm_scatter_s: float = 1e-6          # one double-buffered scatter DMA that
                                          # installs a staged admit group into
                                          # HBM cache slots; overlapped with the

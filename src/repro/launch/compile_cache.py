@@ -1,9 +1,9 @@
 """JAX's persistent compilation cache, and a count of compiles.
 
 Every entry point that compiles for the chip (``chip_smoke.py``,
-``repro.launch.serve``, ``benchmarks/run.py``) calls ``enable()`` before its
-first compile.  Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads
-it and no other directory is set.  Otherwise the cache lives at a fixed path,
+``repro.launch.serve``) calls ``enable()`` before its first compile.  Where
+``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no other
+directory is set.  Otherwise the cache lives at a fixed path,
 ``<repo>/.jax_cache``: the path is part of each entry's key, so a directory
 that moved between runs would never hit.
 """

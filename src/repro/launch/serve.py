@@ -33,9 +33,8 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--workers", type=int, default=4)
     ap.add_argument("--L", type=int, default=64)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--backend", default="default",
-                    choices=list(distance.BACKENDS) + ["default"],
-                    help="distance engine (default: the process default, batch)")
+    ap.add_argument("--backend", default="batch", choices=distance.BACKENDS,
+                    help="distance engine")
     ap.add_argument("--device-beam", action="store_true",
                     help="fused on-device beam step")
     ap.add_argument("--hbm-tier", action="store_true",
@@ -75,10 +74,10 @@ def main(argv=None, index=None):
         params=baselines.SearchParams(L=args.L, W=4),
         seed=args.seed,
         distance_backend=args.backend,
-        device_beam=args.device_beam or None,
-        hbm_tier=args.hbm_tier or None,
-        fuse=args.fuse or None,
-        shared_rendezvous=args.shared_rendezvous or None,
+        device_beam=args.device_beam,
+        hbm_tier=args.hbm_tier,
+        fuse=args.fuse,
+        shared_rendezvous=args.shared_rendezvous,
     )
     system = baselines.build_system(args.system, ds.base, graph, qb, cfg)
     out = baselines.evaluate(system, ds)

@@ -49,7 +49,7 @@ def tiny():
     return ds, graph, qb
 
 
-def _run(tiny, algo, device_beam, fuse, backend="default", n_shards=None,
+def _run(tiny, algo, device_beam, fuse, backend="batch", n_shards=None,
          batch_size=1, n_workers=1):
     ds, graph, qb = tiny
     cfg = baselines.SystemConfig(
@@ -97,6 +97,26 @@ def test_device_beam_bitwise_parity_with_host(algo, backend, fuse, tiny):
 
 
 @pytest.mark.parametrize("algo", ALGOS)
+def test_device_beam_downloads_collapse(algo, tiny):
+    """With the beam on the engine, only the refine stream ships distances
+    home: at most ~1.15 downloads a hop, and 0.6 of the host plane's total
+    or less (none at all for the in-memory system)."""
+    ds, graph, qb = tiny
+    per = {}
+    for device_beam in (False, True):
+        cfg = baselines.SystemConfig(
+            buffer_ratio=0.2, n_workers=2, batch_size=4,
+            device_beam=device_beam, params=SearchParams(L=24, W=4),
+        )
+        sys_ = baselines.build_system(algo, ds.base, graph, qb, cfg)
+        per[device_beam] = baselines.evaluate(sys_, ds)
+    dev, host = per[True], per[False]
+    assert dev["beam_ops"] > 0
+    assert dev["downloads_per_query"] <= 1.15 * max(dev["mean_hops"], 1e-9)
+    assert dev["downloads_per_query"] <= 0.6 * host["downloads_per_query"]
+
+
+@pytest.mark.parametrize("algo", ALGOS)
 def test_device_beam_recall_level_at_interleaved_batch(algo, tiny):
     """B>1 interleaves coroutines, so trajectories may shift; the result
     QUALITY must not (recall within 0.02 of the host plane)."""
@@ -108,8 +128,11 @@ def test_device_beam_recall_level_at_interleaved_batch(algo, tiny):
 
 
 def test_device_beam_off_is_the_default(tiny):
-    sys_, _, stats = _run(tiny, "velo", None, False)
-    assert sys_.config.device_beam is False or not sys_.config.device_beam
+    ds, graph, qb = tiny
+    sys_ = baselines.build_system("velo", ds.base, graph, qb,
+                                  baselines.SystemConfig())
+    _, stats = sys_.run(ds.queries)
+    assert sys_.config.device_beam is False
     assert stats.beam_ops == 0
 
 

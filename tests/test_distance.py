@@ -191,32 +191,39 @@ def test_record_matrix_ext8(small_ds, small_graph):
 def test_get_engine_selection_rules():
     assert distance.get_engine("scalar").name == "scalar"
     assert distance.get_engine("batch").name == "batch"
-    prev = distance.default_backend()
-    try:
-        distance.set_default_backend("scalar")
-        assert distance.get_engine("default").name == "scalar"
-        assert distance.get_engine(None).name == "scalar"
-    finally:
-        distance.set_default_backend(prev)
     with pytest.raises(ValueError):
         distance.get_engine("not-a-backend")
-    with pytest.raises(ValueError):
-        distance.set_default_backend("not-a-backend")
     # no silent substitute: the device engine is what "pallas" serves, and
-    # an alias that could resolve elsewhere does not exist
+    # no alias resolves to an engine the caller did not name
     assert distance.get_engine("pallas").name == "pallas"
-    with pytest.raises(ValueError):
-        distance.get_engine("auto")
+    for alias in ("auto", "default", None):
+        with pytest.raises(ValueError):
+            distance.get_engine(alias)
 
 
-def test_search_context_defaults_to_process_backend(small_ds, small_graph, small_qb):
-    prev = distance.default_backend()
-    try:
-        distance.set_default_backend("scalar")
-        cfg = baselines.SystemConfig(distance_backend="default")
-        sys_ = baselines.build_system(
-            "velo", small_ds.base, small_graph, small_qb, cfg
-        )
-        assert sys_.ctx.dist.name == "scalar"
-    finally:
-        distance.set_default_backend(prev)
+def test_system_config_defaults_to_batch_engine(small_ds, small_graph, small_qb):
+    sys_ = baselines.build_system(
+        "velo", small_ds.base, small_graph, small_qb, baselines.SystemConfig()
+    )
+    assert sys_.ctx.dist.name == "batch"
+
+
+def test_system_config_defaults_are_explicit():
+    """Every serving option is a plain field whose default is what the
+    system runs; no module keeps a process-wide default that could change
+    it behind the caller's back."""
+    cfg = baselines.SystemConfig()
+    assert cfg.fuse is False
+    assert cfg.fuse_rows == 256
+    assert cfg.shared_rendezvous is False
+    assert cfg.overlap_flush is False
+    assert cfg.hbm_tier is False
+    assert cfg.hbm_slots is None
+    assert cfg.device_beam is False
+    assert cfg.scheduler == "rr"
+    assert cfg.sla_ms is None
+    assert cfg.distance_backend == "batch"
+    for mod in (baselines, distance):
+        hooks = [a for a in dir(mod)
+                 if a.lower().lstrip("_").startswith(("set_default", "default"))]
+        assert hooks == [], (mod.__name__, hooks)

@@ -241,20 +241,10 @@ def test_fused_batch_s_kind_routing():
     assert cost.fused_batch_s(2e-6, kind="full") == pytest.approx(11e-6)
     assert cost.fused_batch_s(2e-6, kind="quant") == pytest.approx(3e-6)
     assert cost.fused_batch_s(2e-6) == pytest.approx(3e-6)
-    # parity default: uncalibrated full dispatch equals the batch dispatch,
-    # so pre-existing full-path charges are bitwise unchanged
+    # parity default: the full dispatch equals the batch dispatch, so
+    # pre-existing full-path charges are bitwise unchanged
     d = CostModel()
     assert d.full_dispatch_s == d.batch_dispatch_s
-
-
-def test_apply_calibration_consumes_full_dispatch():
-    cost = baselines.apply_calibration(
-        CostModel(), "batch",
-        {"batch": {"full_dispatch_s": 7e-6, "hbm_scatter_s": 2e-6,
-                   "not_a_field": 1.0}},
-    )
-    assert cost.full_dispatch_s == pytest.approx(7e-6)
-    assert cost.hbm_scatter_s == pytest.approx(2e-6)
 
 
 # -------------------------------------------------------------- serving plane

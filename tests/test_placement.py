@@ -1,6 +1,7 @@
 """Affinity co-placement invariants (paper §3.4)."""
 
 import numpy as np
+import pytest
 
 from repro.core import placement
 from repro.core.pages import page_lookup, page_records
@@ -99,3 +100,49 @@ def test_affinity_layout_improves_colocation(rng, small_ds, small_graph):
         return num / max(den, 1)
 
     assert coloc_fraction(lay_aff) > 2 * coloc_fraction(lay_seq)
+
+
+# ------------------------------------------------ index size (Table 3)
+
+
+@pytest.fixture(scope="module")
+def gist_like_sizes():
+    """Disk and memory bytes of velo and DiskANN over one GIST-shaped index
+    (d=480, R=24), both at a 20 % buffer ratio."""
+    from repro.core import baselines, vamana
+    from repro.core.dataset import make_dataset
+    from repro.core.quant import RabitQuantizer
+
+    ds = make_dataset(n=300, d=480, n_queries=10, k=10, seed=0)
+    g = vamana.build_vamana(ds.base, R=24, L=48, seed=0)
+    qb = RabitQuantizer(480, seed=0).fit_encode(ds.base)
+    cfg = baselines.SystemConfig(buffer_ratio=0.2)
+    velo = baselines.build_system("velo", ds.base, g, qb, cfg)
+    disk = baselines.build_system("diskann", ds.base, g, qb, cfg)
+    return {
+        "origin": ds.base.nbytes,
+        "velo_disk": velo.disk_bytes(),
+        "diskann_disk": disk.disk_bytes(),
+        "velo_mem": velo.memory_bytes(),
+        "diskann_mem": disk.memory_bytes(),
+    }
+
+
+@pytest.mark.parametrize("claim", [
+    "velo_disk_much_smaller_than_diskann",
+    "velo_disk_smaller_than_origin",
+    "diskann_disk_amplifies_origin",
+    "velo_mem_smaller",
+])
+def test_index_size_claims(claim, gist_like_sizes):
+    """Table 3: velo's compressed disk index is several times smaller than
+    DiskANN's and than the raw vectors, and so is its memory footprint."""
+    s = gist_like_sizes
+    ok = {
+        "velo_disk_much_smaller_than_diskann":
+            s["velo_disk"] < 0.25 * s["diskann_disk"],
+        "velo_disk_smaller_than_origin": s["velo_disk"] < 0.5 * s["origin"],
+        "diskann_disk_amplifies_origin": s["diskann_disk"] > s["origin"],
+        "velo_mem_smaller": s["velo_mem"] < 0.5 * s["diskann_mem"],
+    }[claim]
+    assert ok, s

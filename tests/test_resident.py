@@ -280,8 +280,8 @@ def test_shared_rendezvous_terminates_multi_worker(
 
 
 def test_shared_rendezvous_off_is_default(small_ds, small_graph, small_qb):
-    """SystemConfig.shared_rendezvous=None inherits the process default
-    (False): PR-2 per-worker semantics unless explicitly enabled."""
+    """SystemConfig.shared_rendezvous defaults to False: PR-2 per-worker
+    semantics unless explicitly enabled."""
     sys_, _, _ = _run("velo", small_ds, small_graph, small_qb, fuse=True)
     assert sys_.config.shared_rendezvous is False
 
@@ -363,17 +363,3 @@ def test_table_upload_charged_once(small_ds, small_graph, small_qb):
     _, st_b = sys_b.run(small_ds.queries[:N_QUERIES])
     delta = st_a.makespan_s - st_b.makespan_s
     assert 0.0 < delta <= big * 1.5, delta
-
-
-def test_calibration_overrides_cost_model():
-    from repro.core.sim import CostModel
-
-    cost = CostModel()
-    calib = {"batch": {"batch_dispatch_s": 1.5e-6, "table_upload_s": 9e-5,
-                       "not_a_field": 1.0}}
-    out = baselines.apply_calibration(cost, "batch", calib)
-    assert out.batch_dispatch_s == 1.5e-6 and out.table_upload_s == 9e-5
-    # untouched backend -> untouched model
-    assert baselines.apply_calibration(cost, "pallas", calib) is cost
-    assert baselines.load_calibration(None) is None
-    assert baselines.load_calibration(calib) is calib

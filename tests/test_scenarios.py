@@ -78,11 +78,11 @@ def _run_cell(tiny, algo, backend, fuse, shared, async_load):
         cost=sys_.cost,
         config=EngineConfig(
             n_workers=sys_.config.n_workers,
-            batch_size=sys_.config.batch_size,
+            batch_size=sys_.batch_size,
             page_size=sys_.config.page_size,
-            fuse=bool(sys_.config.fuse),
+            fuse=sys_.config.fuse,
             fuse_rows=sys_.config.fuse_rows,
-            shared_rendezvous=bool(sys_.config.shared_rendezvous),
+            shared_rendezvous=sys_.config.shared_rendezvous,
         ),
         dist=sys_.ctx.dist,
         qb=sys_.ctx.qb,

@@ -150,21 +150,24 @@ def test_combined_table_requires_matching_shapes(tenant_data):
 def test_shared_pool_hot_tenant_hit_rate_beats_partition(tenant_data):
     """The point of sharing: under a zipfian hot-tenant mix the shared pool
     lends cold tenants' slots to the hot one — its hit rate must be at least
-    the static-partition hit rate at the same total byte budget."""
+    the static-partition hit rate at the same total byte budget, and the
+    plane's global hit rate must not fall for it."""
     specs = [_spec(tenant_data, 0, "velo", params=SearchParams(L=32, W=4)),
              _spec(tenant_data, 1, "velo", params=SearchParams(L=32, W=4))]
     cfg = baselines.SystemConfig(buffer_ratio=0.12, n_workers=2, batch_size=4)
     wload = workload_mod.zipfian_mix([30, 30], 120, s=1.8, seed=0)
     hot = int(wload.counts().argmax())
-    rates = {}
+    rates, global_rates = {}, {}
     for shared in (True, False):
         plane = ServingPlane(specs, cfg, shared_pool=shared)
         run = plane.run(wload)
         rates[shared] = run.tenants[hot].stats.hit_rate
+        global_rates[shared] = run.stats.hit_rate
         for tr in run.tenants:
             if tr.recall is not None:
                 assert tr.recall > 0.6, (tr.name, tr.recall)
     assert rates[True] >= rates[False], rates
+    assert global_rates[True] >= global_rates[False], global_rates
 
 
 def test_cross_tenant_fusion_spans_tenants(tenant_data):
